@@ -497,11 +497,11 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
     the draws are per-subtask lognormal like the event simulator's, in
     sid order rather than event order (statistically identical).
     ``backend="pallas"`` runs the sparse ``sim_relax_pop`` kernel on
-    padded predecessor gathers in float32 (falls back to NumPy when JAX
-    is unavailable). ``verify=True`` lints the lowered batch before the
-    sweep and proves the result after it (``repro.analysis``): padding,
-    release floors, in-order + dependency edges incl. comm lag, fault
-    stranding propagation, recomputed makespans.
+    padded predecessor gathers in float32. ``verify=True`` lints the
+    lowered batch before the sweep and proves the result after it
+    (``repro.analysis``): padding, release floors, in-order + dependency
+    edges incl. comm lag, fault stranding propagation, recomputed
+    makespans.
     """
     if not isinstance(batch, ScenarioBatch):
         batch = batch_scenarios(batch)
@@ -514,10 +514,7 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
         # pallas kernel sweeps plain max-plus and would miss the kills
         end = relax_wave_faults(batch, dur)
     elif backend == "pallas":
-        try:
-            end = _relax_pallas(batch, dur)
-        except ImportError:                     # pragma: no cover - no JAX
-            end = relax_wave_np(batch, dur)
+        end = _relax_pallas(batch, dur)
     elif backend == "numpy":
         end = relax_wave_np(batch, dur)
     else:

@@ -42,15 +42,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
 
     def body(kv_i, carry):
         m, l, acc = carry
-        # index the unit batch dim with a length-1 dslice, not a bare int:
-        # jax 0.4.x's interpret-mode load discharge assumes non-Slice
-        # indices are arrays (`s.shape`) and crashes on Python ints
-        k = pl.load(k_ref, (pl.dslice(0, 1),
-                            pl.dslice(kv_i * kv_block, kv_block),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1),
-                            pl.dslice(kv_i * kv_block, kv_block),
-                            slice(None)))[0].astype(jnp.float32)
+        kv = pl.ds(kv_i * kv_block, kv_block)
+        k = k_ref[0, kv, :].astype(jnp.float32)
+        v = v_ref[0, kv, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if softcap is not None:

@@ -1,7 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CPU
-tests and on real hardware (where the compiled Mosaic path runs).
+Each wrapper compiles its kernel with Mosaic on a TPU and interprets it
+anywhere else. Interpret mode exists for the CPU tests only: no chip run
+may rely on it, and ``chip_smoke.py`` fails when a kernel's compiled
+program holds no ``tpu_custom_call``.
 
 The scheduling kernels (``sched_score`` / ``sim_step`` / ``sim_relax`` /
 ``sim_relax_pop``) gather through caller-provided index arrays; an
@@ -87,8 +89,7 @@ def sim_relax(lat, volbw, duration, release, *, n_steps, sub_block=128):
                           sub_block=sub_block, interpret=not _on_tpu())
 
 
-def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps,
-                  sub_block=128):
+def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps):
     b, s, p1 = pred.shape
     check_shape("sim_relax_pop.lat", lat, (b, s, p1))
     check_shape("sim_relax_pop.volbw", volbw, (b, s, p1))
@@ -98,8 +99,7 @@ def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps,
     # slot is the zero sentinel; anything past it reads garbage
     check_gather_bounds(pred, s, "sim_relax_pop.pred")
     return _sim.sim_relax_pop(pred, lat, volbw, duration, release,
-                              n_steps=n_steps, sub_block=sub_block,
-                              interpret=not _on_tpu())
+                              n_steps=n_steps, interpret=not _on_tpu())
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, scale=None, softcap=None,

@@ -1,7 +1,7 @@
-"""NumPy-only scheduler-scoring helpers — the JAX-free leaf that both
-the Pallas kernel (``sched_score.py``), the oracle registry (``ref.py``)
-and the admission policies import, so ``BatchedPolicy``'s kernel scorer
-can degrade gracefully when JAX is absent."""
+"""NumPy-only scheduler-scoring helpers — the JAX-free leaf that the
+Pallas kernel (``sched_score.py``) and the oracle registry (``ref.py``)
+import; ``sched_score_np`` is the reference the kernel is checked
+against."""
 
 from __future__ import annotations
 

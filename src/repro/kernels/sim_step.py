@@ -170,19 +170,42 @@ def pop_relax_np(pred, lat, volbw, duration, release, *,
     return np.array(end[:, :s])
 
 
+_TILE = (8, 128)                      # one f32 vreg: (sublanes, lanes)
+
+
 def _pop_step_kernel(end_ref, pred_ref, lat_ref, volbw_ref, dur_ref,
                      rel_ref, o_ref):
-    end = end_ref[0]                          # (Sp,) current finish times
-    gath = jnp.take(end, pred_ref[0], axis=0)            # (sb, P)
-    ready = jnp.max((gath + lat_ref[0]) + volbw_ref[0], axis=-1)
-    o_ref[0] = dur_ref[0] + jnp.maximum(rel_ref[0],
-                                        jnp.maximum(ready, 0.0))
+    """One sweep over an (8, 128) tile of (candidates, subtasks).
+
+    ``end_ref`` holds the tile's 8 rows of current finish times, all
+    Sp columns. Mosaic gathers only within one vreg, along the lanes,
+    so each predecessor column is gathered 128 lanes at a time: chunk
+    ``c`` of the rows answers the sources that fall in it."""
+    lanes = _TILE[1]
+    n_chunk = end_ref.shape[1] // lanes
+
+    def edge(j, ready):
+        idx = pred_ref[j]                                  # (8, 128)
+        lane, chunk = idx & (lanes - 1), idx >> 7          # lanes = 2**7
+
+        def pick(c, g):
+            src = end_ref[:, pl.ds(pl.multiple_of(c * lanes, lanes), lanes)]
+            return jnp.where(chunk == c,
+                             jnp.take_along_axis(src, lane, axis=1), g)
+
+        g = jax.lax.fori_loop(0, n_chunk, pick,
+                              jnp.zeros(idx.shape, jnp.float32))
+        return jnp.maximum(ready, (g + lat_ref[j]) + volbw_ref[j])
+
+    ready = jax.lax.fori_loop(0, pred_ref.shape[0], edge,
+                              jnp.full(o_ref.shape, -jnp.inf, jnp.float32))
+    o_ref[...] = dur_ref[...] + jnp.maximum(rel_ref[...],
+                                            jnp.maximum(ready, 0.0))
 
 
-@functools.partial(jax.jit, static_argnames=("n_steps", "sub_block",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("n_steps", "interpret"))
 def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps: int,
-                  sub_block: int = 128, interpret: bool = False):
+                  interpret: bool = False):
     """Iterate the sparse population sweep ``n_steps`` times from zeros.
 
     Inputs are the padded-CSR gather form: ``pred`` (B, S, P) int32
@@ -191,37 +214,40 @@ def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps: int,
     end buffer keeps one extra 128-aligned region whose rows evaluate
     to exactly 0 every sweep (0 duration, 0 release, all-(-inf) lags),
     so the sentinel slot needs no special handling inside the kernel.
-    Returns (B, S) float32 finish times."""
+    Returns (B, S) float32 finish times.
+
+    The per-edge operands are laid out (P, B, S), so every block is
+    (P, 8, 128) or (8, 128): the TPU tiling. B is padded to a multiple
+    of 8 with rows that stay 0."""
     pred = jnp.asarray(pred, jnp.int32)
     lat = jnp.asarray(lat, jnp.float32)
     volbw = jnp.asarray(volbw, jnp.float32)
     duration = jnp.asarray(duration, jnp.float32)
     release = jnp.asarray(release, jnp.float32)
     b, s, p = pred.shape
-    sp = max(sub_block, ((s + 1 + 127) // 128) * 128)
-    sb = min(sub_block, sp)
-    pad = sp - s
-    pred = _pad_axis(pred, 1, pad, s)
-    lat = _pad_axis(lat, 1, pad, -jnp.inf)
-    volbw = _pad_axis(volbw, 1, pad, -jnp.inf)
-    duration = _pad_axis(duration, 1, pad, 0.0)
-    release = _pad_axis(release, 1, pad, 0.0)
+    bb, sb = _TILE
+    bp = -(-b // bb) * bb
+    sp = -(-(s + 1) // sb) * sb
 
+    def lay(x, value):                  # (B, S[, P]) -> padded (P, Bp, Sp)
+        x = _pad_axis(_pad_axis(x, 0, bp - b, value), 1, sp - s, value)
+        return jnp.moveaxis(x, 2, 0) if x.ndim == 3 else x
+
+    pred, lat, volbw = lay(pred, s), lay(lat, -jnp.inf), lay(volbw, -jnp.inf)
+    duration, release = lay(duration, 0.0), lay(release, 0.0)
+    edge = pl.BlockSpec((p, bb, sb), lambda i, j: (0, i, j))
+    node = pl.BlockSpec((bb, sb), lambda i, j: (i, j))
     call = pl.pallas_call(
         _pop_step_kernel,
-        grid=(b, sp // sb),
-        in_specs=[pl.BlockSpec((1, sp), lambda i, j: (i, 0)),
-                  pl.BlockSpec((1, sb, p), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, sb, p), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, sb, p), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, sb), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, sp), jnp.float32),
+        grid=(bp // bb, sp // sb),
+        in_specs=[pl.BlockSpec((bb, sp), lambda i, j: (i, 0)),
+                  edge, edge, edge, node, node],
+        out_specs=node,
+        out_shape=jax.ShapeDtypeStruct((bp, sp), jnp.float32),
         interpret=interpret,
     )
     end = jax.lax.fori_loop(
         0, n_steps,
         lambda _, e: call(e, pred, lat, volbw, duration, release),
-        jnp.zeros((b, sp), jnp.float32))
-    return end[:, :s]
+        jnp.zeros((bp, sp), jnp.float32))
+    return end[:b, :s]

@@ -7,7 +7,9 @@ from __future__ import annotations
 import jax
 import numpy as np
 
-from repro.jax_compat import mesh_axis_types_kwargs
+
+def _auto(axes):
+    return (jax.sharding.AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -19,15 +21,14 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    """Mesh over the first prod(shape) devices (GSPMD auto axes where
-    the installed jax types mesh axes)."""
+    """Mesh over the first prod(shape) devices, every axis GSPMD auto."""
     n = int(np.prod(shape))
     devices = jax.devices()
     if len(devices) == n:     # topology-aware ordering when the mesh fits
-        return jax.make_mesh(shape, axes, **mesh_axis_types_kwargs(len(axes)))
+        return jax.make_mesh(shape, axes, axis_types=_auto(axes))
     if len(devices) < n:
         raise RuntimeError(
             f"need {n} devices, have {len(devices)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512")
     arr = np.asarray(devices[:n]).reshape(shape)
-    return jax.sharding.Mesh(arr, axes, **mesh_axis_types_kwargs(len(axes)))
+    return jax.sharding.Mesh(arr, axes, axis_types=_auto(axes))

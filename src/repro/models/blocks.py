@@ -22,7 +22,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import shard_map
 from . import moe as moe_lib
 from .layers import (NEG_INF, apply_rope, attention, glu_mlp, rms_norm,
                      softcap)
@@ -113,7 +112,7 @@ def _shard_map_seq_attention(q, k, v, *, cfg, ctx, window, scale,
 
     prefix = prefix_len if prefix_len is not None else \
         jnp.zeros((q.shape[0],), jnp.int32)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(dp, ax, None, None), P(dp, None, None, None),
                   P(dp, None, None, None), P(dp)),

@@ -16,8 +16,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..jax_compat import pvary
-
 
 # ---------------------------------------------------------------------------
 # norms / activations / embeddings
@@ -145,7 +143,7 @@ def attention_streamed(q: jax.Array, k: jax.Array, v: jax.Array, *,
     m0 = jnp.full((b, hkv, g, s), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, s), jnp.float32)
     if vma_axes:    # under shard_map the scan carry must be device-varying
-        acc0, m0, l0 = (pvary(t, vma_axes) for t in (acc0, m0, l0))
+        acc0, m0, l0 = (jax.lax.pvary(t, vma_axes) for t in (acc0, m0, l0))
 
     def body(carry, blk):
         acc, m, l = carry
@@ -315,7 +313,7 @@ def _flash_fwd_impl(q, k, v, q_pos, scale, causal, softcap_v, kv_block,
     m0 = jnp.full((b, hkv, g, s), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hkv, g, s), jnp.float32)
     if vma_axes:
-        acc0, m0, l0 = (pvary(x, vma_axes) for x in (acc0, m0, l0))
+        acc0, m0, l0 = (jax.lax.pvary(x, vma_axes) for x in (acc0, m0, l0))
 
     def body(carry, blk):
         acc, m, l = carry
@@ -367,7 +365,7 @@ def _flash_bwd(scale, causal, softcap_v, kv_block, vma_axes, t_valid,
 
     dq0 = jnp.zeros((b, s, hq, d), jnp.float32)
     if vma_axes:
-        dq0 = pvary(dq0, vma_axes)
+        dq0 = jax.lax.pvary(dq0, vma_axes)
 
     def body(dq_acc, blk):
         kblk, vblk, idx = blk

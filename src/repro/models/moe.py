@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..jax_compat import shard_map
-
 from .layers import glu_mlp
 
 
@@ -163,7 +161,7 @@ def moe_a2a(x: jax.Array, params: dict, *, top_k: int, activation: str,
         return y.reshape(bl, sl, d), aux
 
     spec_x = P(dp_axes, ep_axis, None)
-    out = shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_x, P(), P(ep_axis, None, None, None),
                   P(ep_axis, None, None)),
@@ -205,7 +203,7 @@ def moe_local_decode(x: jax.Array, params: dict, *, top_k: int,
         return y.reshape(bl, s, d).astype(x_loc.dtype), aux
 
     spec_x = P(dp_axes, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_x, P(), P(ep_axis, None, None, None),
                   P(ep_axis, None, None)),

@@ -136,21 +136,15 @@ class BatchedPolicy(Policy):
         """One batched ``sched_score`` call over the (apps × cores)
         candidate matrix; per-app score = best core's drain estimate,
         relative to ``now`` like the exact scorer. The drain matrix
-        comes off the shared scenario IR (``core.lowering``); the score
-        degrades to the NumPy oracle when JAX is unavailable
-        (``sched_ref`` is the JAX-free leaf both paths share)."""
+        comes off the shared scenario IR (``core.lowering``)."""
         import numpy as np
 
         from ..core.lowering import drain_matrix
-        from ..kernels.sched_ref import sched_score_np
+        from ..kernels.ops import sched_score
         drain = drain_matrix([a.graph for a in batch], eng.machine)
         frontiers = eng.state.frontiers()
         release = [max(now, a.t_arrival) for a in batch]
-        try:
-            from ..kernels.ops import sched_score
-            matrix = np.asarray(sched_score(drain, frontiers, release))
-        except ImportError:                  # pragma: no cover - no JAX
-            matrix = sched_score_np(drain, frontiers, release)
+        matrix = np.asarray(sched_score(drain, frontiers, release))
         return [float(v) - now for v in matrix.min(axis=1)]
 
 

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..jax_compat import pvary, shard_map
-
 
 def plan_stages(n_layers: int, n_pods: int, layer_flops: float,
                 act_bytes: float, *, pod_speed_flops: float | None = None,
@@ -88,8 +86,8 @@ def gpipe(stage_fn, stage_params, x_micro, *, pod_axis: str, mesh,
         p = jax.lax.axis_index(pod_axis)
         n_pods = jax.lax.psum(1, pod_axis)
         total = n_micro + n_pods - 1
-        buf = pvary(jnp.zeros_like(xm[0]), (pod_axis,))
-        out0 = pvary(jnp.zeros_like(xm), (pod_axis,))
+        buf = jax.lax.pvary(jnp.zeros_like(xm[0]), (pod_axis,))
+        out0 = jax.lax.pvary(jnp.zeros_like(xm), (pod_axis,))
         perm = [(i, i + 1) for i in range(n_pods - 1)]
 
         def tick(carry, t):
@@ -116,7 +114,7 @@ def gpipe(stage_fn, stage_params, x_micro, *, pod_axis: str, mesh,
     n_stages = jax.tree.leaves(stage_params)[0].shape[0]
     pspec = jax.tree.map(
         lambda t: P(pod_axis, *([None] * (t.ndim - 1))), stage_params)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(pspec, P(None, *in_spec)),
         out_specs=P(None, *in_spec))(stage_params, x_micro)

@@ -23,10 +23,7 @@ from dataclasses import dataclass, field
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..jax_compat import abstract_mesh
-
-__all__ = ["MeshAxes", "Partitioner", "abstract_mesh",
-           "permute_expert_params"]
+__all__ = ["MeshAxes", "Partitioner", "permute_expert_params"]
 
 
 @dataclass(frozen=True)

@@ -26,7 +26,7 @@ def _build(suite: str) -> Built:
 
 
 def build_f64(suite: str = "8core") -> Built:
-    """Only meaningful under ``jax.experimental.enable_x64`` — with
+    """Only meaningful under ``jax.enable_x64`` — with
     x64 off, jax canonicalises the cast back to f32."""
     return Built(fn=_f64, args=(jnp.ones(16, jnp.float32),))
 

@@ -380,7 +380,7 @@ def test_critical_policy_orders_by_tier_and_reports_tier_metrics():
 # ---------------------------------------------------------------------------
 
 try:
-    from hypothesis import given, settings, strategies as st_
+    from hypothesis import example, given, settings, strategies as st_
     HAVE_HYPOTHESIS = True
 except ImportError:                          # pragma: no cover
     HAVE_HYPOTHESIS = False
@@ -407,6 +407,8 @@ if HAVE_HYPOTHESIS:
 
     @given(seed=st_.integers(0, 30), fseed=st_.integers(0, 2**31 - 1),
            frac=st_.floats(0.1, 0.9))
+    # the first recovery cannot see a failure scripted after `at`
+    @example(seed=0, fseed=0, frac=0.25)
     @settings(max_examples=15, deadline=None)
     def test_recovery_validity_property(seed, fseed, frac):
         eng = loaded_engine(n_apps=5, seed=seed)
@@ -414,8 +416,22 @@ if HAVE_HYPOTHESIS:
         script = random_script(eng.machine.n_cores, seed=fseed,
                                horizon=ms, n_fail=1, n_slow=1,
                                n_degrade=0, protect=(0,))
-        recover_from_script(eng, script, ms * frac)
-        eng.state.validate()            # no overlap, no pre-release
+        at = ms * frac
         fail_t = script.fail_times(eng.machine.n_cores)
+        report = recover_from_script(eng, script, at)
+        eng.state.validate()            # no overlap, no pre-release
+        # detection at `at` sees exactly the failures scripted by then
+        assert set(report.dead_cores) == {c for c, t in enumerate(fail_t)
+                                          if t <= at}
         for sid, p in eng.state.schedule.placements.items():
-            assert p.end <= fail_t[p.core] + 1e-9
+            # recovery can only route around failures detected by `at`
+            if fail_t[p.core] <= at:
+                assert p.end <= fail_t[p.core] + 1e-9
+        # a failure scripted after `at` is routed around once detected
+        later = max([t for t in fail_t if at < t < float("inf")],
+                    default=None)
+        if later is not None:
+            recover_from_script(eng, script, later)
+            eng.state.validate()
+            for sid, p in eng.state.schedule.placements.items():
+                assert p.end <= fail_t[p.core] + 1e-9

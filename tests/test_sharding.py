@@ -32,8 +32,8 @@ def test_partition_specs_divisibility():
     checked abstractly (no devices needed) for all 10 archs on a
     simulated 16x16 mesh via AbstractMesh."""
     from repro.launch.specs import abstract_params
-    from repro.sharding.partition import Partitioner, abstract_mesh
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    from repro.sharding.partition import Partitioner
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     sizes = {"data": 16, "model": 16}
     for name, cfg in ARCHS.items():
         part = Partitioner(mesh, MeshAxes(("data",), "model",

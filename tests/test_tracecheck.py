@@ -46,7 +46,7 @@ def test_retrace_counts_cache_growth():
 
 def test_f64_defect_under_x64():
     from defects.dtype import ENTRY_F64
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         report = trace_entry(ENTRY_F64, "8core", hlo=False)
     assert "dtype" in {v.kind for v in report.violations}
     assert any("float64" in v.message for v in report.violations)
