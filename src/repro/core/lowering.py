@@ -37,6 +37,7 @@ from typing import Any
 
 import numpy as np
 
+from .. import obs
 from .machine import MachineModel
 from .mpaha import AppGraph
 
@@ -161,7 +162,9 @@ def graph_arrays(graph: AppGraph) -> GraphArrays:
     fp = (len(graph.subtasks), len(graph.edges))
     cached = getattr(graph, "_graph_arrays", None)
     if cached is not None and cached[0] == fp:
+        obs.count("lower.graph_arrays.hit")
         return cached[1]
+    obs.count("lower.graph_arrays.miss")
     graph.finalize()
     pred_ptr, pred_sid, pred_vol = _csr(graph.preds)
     succ_ptr, succ_sid, succ_vol = _csr(graph.succs)
@@ -662,7 +665,9 @@ def population_arrays(graph: AppGraph, machine: MachineModel
     cached = getattr(graph, "_population_arrays", None)
     fp = (len(graph.subtasks), len(graph.edges))
     if cached is not None and cached[0] == fp and cached[1] is ma:
+        obs.count("lower.population_arrays.hit")
         return cached[2]
+    obs.count("lower.population_arrays.miss")
     s = ga.n_subtasks
     indeg = (ga.pred_ptr[1:] - ga.pred_ptr[:-1]).tolist()
     succ_ptr, succ_sid = ga.succ_ptr.tolist(), ga.succ_sid.tolist()

@@ -35,6 +35,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import obs
 from .lowering import (ScenarioArrays, ScenarioBatch, batch_scenarios,
                        lower_scenario)
 from .machine import MachineModel
@@ -504,11 +505,13 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
     makespans.
     """
     if not isinstance(batch, ScenarioBatch):
-        batch = batch_scenarios(batch)
+        with obs.span("suite.batch"):
+            batch = batch_scenarios(batch)
     if verify:
         from ..analysis.ir_lint import lint_batch
         lint_batch(batch)
-    dur = _jitter_durations(batch, jitter, seeds)
+    with obs.span("suite.jitter"):
+        dur = _jitter_durations(batch, jitter, seeds)
     if batch.has_faults:
         # the fault semantics live only in the NumPy wave path; the
         # pallas kernel sweeps plain max-plus and would miss the kills
@@ -560,12 +563,15 @@ def _pop_gather_inputs(batch: ScenarioBatch):
 
 def _relax_pallas(batch: ScenarioBatch, duration: np.ndarray) -> np.ndarray:
     from ..kernels.ops import sim_relax_pop
-    pred, lat, volbw = _pop_gather_inputs(batch)
-    end = sim_relax_pop(pred, lat, volbw, duration, batch.release,
-                        n_steps=batch.depth)
-    return np.asarray(end, np.float64)
+    with obs.span("suite.gather"):
+        pred, lat, volbw = _pop_gather_inputs(batch)
+    with obs.span("suite.relax"):
+        end = sim_relax_pop(pred, lat, volbw, duration, batch.release,
+                            n_steps=batch.depth)
+        return np.asarray(end, np.float64)
 
 
+@obs.spanned("suite.call")
 def simulate_suite(graphs: list[AppGraph], machines, schedules, *,
                    jitter: float = 0.0, seeds=None,
                    releases: list[dict[int, float] | None] | None = None,
@@ -588,8 +594,10 @@ def simulate_suite(graphs: list[AppGraph], machines, schedules, *,
             f"scenario parts disagree: {len(graphs)} graphs, "
             f"{len(machines)} machines, {len(schedules)} schedules, "
             f"{len(rel)} release maps, {len(faults)} fault scripts")
-    scenarios = [lower_scenario(g, m, s, releases=r, faults=f)
-                 for g, m, s, r, f in zip(graphs, machines, schedules,
-                                          rel, faults)]
+    obs.count("suite.scenarios", len(graphs))
+    with obs.span("suite.lower"):
+        scenarios = [lower_scenario(g, m, s, releases=r, faults=f)
+                     for g, m, s, r, f in zip(graphs, machines, schedules,
+                                              rel, faults)]
     return simulate_batch(scenarios, jitter=jitter, seeds=seeds,
                           backend=backend, verify=verify)

@@ -52,6 +52,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..core import lowering
 from ..core.machine import MachineModel
 from ..core.mpaha import AppGraph
@@ -251,6 +252,7 @@ def _generation(inp: DevicePopulation, key: jnp.ndarray,
     ops. Selection is tournament-of-``k`` by fitness gather; a
     ``elite_bias`` fraction of first parents comes from the sorted
     elite pool; the top ``elite`` rows survive unchanged."""
+    obs.count("ga.step_traces")     # the body runs only while JAX traces
     b, t = pop.shape
     order = jnp.argsort(fit)
     pop, fit = pop[order], fit[order]
@@ -276,9 +278,10 @@ def _generation(inp: DevicePopulation, key: jnp.ndarray,
 def generation_step(params: Any, *, n_tasks: int, n_cores: int,
                     method: str = "scan") -> Callable:
     """The jitted ``(inp, key, pop, fit) -> (pop, fit)`` generation step
-    :func:`ga_search_device` iterates — exposed so the benchmark can
-    time one device generation in isolation (warm the jit cache with
-    one call first)."""
+    :func:`ga_search_device` iterates. Also built on its own by the
+    compiled entry-point manifest (``analysis/entrypoints.py``), the
+    chip smoke test (``chip_smoke.py``) and the TPU compile tests
+    (``tests/test_tpu_compile.py``)."""
     p_mut = params.p_mutation if params.p_mutation is not None \
         else max(1.0 / max(n_tasks, 1), 0.02)
     return jax.jit(functools.partial(
@@ -303,32 +306,36 @@ def ga_search_device(graph: AppGraph, machine: MachineModel, *,
     from .ga import GAParams
 
     par = params or GAParams()
-    graph.finalize()
-    n_tasks = len(graph.tasks)
-    n_cores = machine.n_cores
     if method is None:
         method = "kernel" if jax.default_backend() == "tpu" else "scan"
-    inp = device_inputs(graph, machine, releases=releases)
-    key = jax.random.PRNGKey(seed)
-    key, k0 = jax.random.split(key)
-    pop = jax.random.randint(k0, (par.pop_size, n_tasks), 0,
-                             max(n_cores, 1), jnp.int32)
-    if elites:
-        seeded = np.array(pop)
-        for i, e in enumerate(elites[:par.pop_size]):
-            seeded[i] = np.asarray(e, np.int32)
-        pop = jnp.asarray(seeded)
+    with obs.span("ga.inputs"):
+        graph.finalize()
+        n_tasks = len(graph.tasks)
+        n_cores = machine.n_cores
+        inp = device_inputs(graph, machine, releases=releases)
+        key = jax.random.PRNGKey(seed)
+        key, k0 = jax.random.split(key)
+        pop = jax.random.randint(k0, (par.pop_size, n_tasks), 0,
+                                 max(n_cores, 1), jnp.int32)
+        if elites:
+            seeded = np.array(pop)
+            for i, e in enumerate(elites[:par.pop_size]):
+                seeded[i] = np.asarray(e, np.int32)
+            pop = jnp.asarray(seeded)
 
     fitness = functools.partial(population_fitness_device, method=method)
-    step = generation_step(par, n_tasks=n_tasks, n_cores=n_cores,
-                           method=method)
-    fit = fitness(inp, pop)
-    for _ in range(par.generations):
-        key, kg = jax.random.split(key)
-        pop, fit = step(inp, kg, pop, fit)
-
-    best = int(jnp.argmin(fit))
-    vec, val = np.asarray(pop[best], np.int32).copy(), float(fit[best])
+    with obs.span("ga.generations"):
+        step = generation_step(par, n_tasks=n_tasks, n_cores=n_cores,
+                               method=method)
+        fit = fitness(inp, pop)
+        obs.count("ga.candidates", par.pop_size)
+        for _ in range(par.generations):
+            key, kg = jax.random.split(key)
+            pop, fit = step(inp, kg, pop, fit)
+            obs.count("ga.generations")
+            obs.count("ga.candidates", par.pop_size)
+        best = int(jnp.argmin(fit))
+        vec, val = np.asarray(pop[best], np.int32).copy(), float(fit[best])
     if par.refine_rounds > 0 and n_tasks > 0 and n_cores > 1:
         key, kr = jax.random.split(key)
         vec, val = hill_climb_device(fitness, inp, vec, val, key=kr,

@@ -39,6 +39,7 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
+from .. import obs
 from ..core import lowering
 from ..core.machine import MachineModel
 from ..core.mpaha import AppGraph
@@ -204,6 +205,7 @@ def ga_search(graph: AppGraph, machine: MachineModel, *, seed: int = 0,
     return vec, val
 
 
+@obs.spanned("ga.schedule")
 def ga_schedule(graph: AppGraph, machine: MachineModel, *, seed: int = 0,
                 params: GAParams | None = None, baseline: str = "engine",
                 releases: dict[int, float] | None = None,
@@ -218,16 +220,18 @@ def ga_schedule(graph: AppGraph, machine: MachineModel, *, seed: int = 0,
     par = params or GAParams()
     if overrides:
         par = replace(par, **overrides)
-    base_sched = get_scheduler(baseline)(graph, machine)
-    if len(graph.tasks) == 0:
-        return base_sched
-    elite = encode(graph, base_sched)
-    if releases:
-        # the heuristic scheduled without the floors; keep its *mapping*
-        # as the elite but re-decode it under the floors so the fallback
-        # candidate also respects the requested release semantics
-        base_sched = decode(graph, machine, elite, releases=releases)
+    with obs.span("ga.baseline"):
+        base_sched = get_scheduler(baseline)(graph, machine)
+        if len(graph.tasks) == 0:
+            return base_sched
+        elite = encode(graph, base_sched)
+        if releases:
+            # the heuristic scheduled without the floors; keep its *mapping*
+            # as the elite but re-decode it under the floors so the fallback
+            # candidate also respects the requested release semantics
+            base_sched = decode(graph, machine, elite, releases=releases)
     vec, _ = ga_search(graph, machine, seed=seed, params=par,
                        elites=[elite], releases=releases)
-    cand = decode(graph, machine, vec, releases=releases)
-    return cand if cand.makespan() <= base_sched.makespan() else base_sched
+    with obs.span("ga.decode"):
+        cand = decode(graph, machine, vec, releases=releases)
+        return cand if cand.makespan() <= base_sched.makespan() else base_sched

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs
 from ..core import lowering
 from ..core.machine import MachineModel
 from ..core.mpaha import AppGraph
@@ -86,19 +87,23 @@ def hill_climb_device(fitness_fn, inp, vec: np.ndarray, fit: float, *,
         return vec, fit
     full = n_tasks * (n_cores - 1)
     m = min(moves, full)
-    rows = jnp.arange(m)
-    for _ in range(rounds):
-        key, kn = jax.random.split(key)
-        flat = jax.random.choice(kn, full, (m,), replace=False)
-        tasks = flat // (n_cores - 1)
-        shift = flat % (n_cores - 1)
-        base = jnp.asarray(vec)
-        new_core = jnp.where(shift < base[tasks], shift, shift + 1)
-        neigh = jnp.tile(base, (m, 1)).at[rows, tasks].set(
-            new_core.astype(jnp.int32))
-        f = np.asarray(fitness_fn(inp, neigh))
-        best = int(np.argmin(f))
-        if f[best] >= fit - 1e-12:
-            break
-        vec, fit = np.asarray(neigh[best], np.int32).copy(), float(f[best])
+    with obs.span("ga.refine"):
+        rows = jnp.arange(m)
+        for _ in range(rounds):
+            key, kn = jax.random.split(key)
+            flat = jax.random.choice(kn, full, (m,), replace=False)
+            tasks = flat // (n_cores - 1)
+            shift = flat % (n_cores - 1)
+            base = jnp.asarray(vec)
+            new_core = jnp.where(shift < base[tasks], shift, shift + 1)
+            neigh = jnp.tile(base, (m, 1)).at[rows, tasks].set(
+                new_core.astype(jnp.int32))
+            f = np.asarray(fitness_fn(inp, neigh))
+            obs.count("ga.refine_rounds")
+            obs.count("ga.candidates", m)
+            best = int(np.argmin(f))
+            if f[best] >= fit - 1e-12:
+                break
+            vec, fit = (np.asarray(neigh[best], np.int32).copy(),
+                        float(f[best]))
     return vec, fit
