@@ -201,6 +201,10 @@ def lint_batch(batch: lowering.ScenarioBatch) -> None:
     check_shape("n_sub", batch.n_sub, (b,))
     if b and (batch.n_sub.min() < 0 or batch.n_sub.max() > s):
         _fail("n_sub", f"subtask counts outside [0, {s}]")
+    _check_int("n_rows", batch.n_rows)
+    check_shape("n_rows", batch.n_rows, (b,))
+    if b and (np.any(batch.n_rows < batch.n_sub) or batch.n_rows.max() > s):
+        _fail("n_rows", f"row counts outside [n_sub, {s}]")
     for name, arr in (("duration", batch.duration),
                       ("release", batch.release), ("wave", batch.wave)):
         check_shape(name, arr, (b, s))
@@ -226,10 +230,28 @@ def lint_batch(batch: lowering.ScenarioBatch) -> None:
     if np.any(batch.pred_lat[real] < 0) or np.any(batch.pred_volbw[real] < 0):
         _fail("pred_lat", "real-edge lags must be >= 0")
     # padded rows must be inert: no work, no edges
-    inv = ~valid
+    live = batch.live
+    inv = ~live
     if np.any(batch.duration[inv] != 0) or np.any(batch.prev[inv] != s) \
             or np.any(batch.pred[inv] != s):
         _fail("n_sub", "padded subtask rows carry work or edges")
+    # join rows: no work, no floor, no core, at least one edge, and read
+    # by exactly one slot of a live row
+    join = live & ~valid
+    if np.any(join):
+        if np.any(batch.duration[join] != 0) \
+                or np.any(batch.release[join] != 0) \
+                or np.any(batch.prev[join] != s):
+            _fail("n_rows", "join rows carry work, a floor or a core")
+        if np.any(~real.any(axis=2)[join]):
+            _fail("n_rows", "join rows without an edge")
+        reads = np.zeros((b, s + 1), np.int64)
+        slot = live[:, :, None] & real
+        np.add.at(reads, (np.broadcast_to(np.arange(b)[:, None, None],
+                                          batch.pred.shape)[slot],
+                          batch.pred[slot]), 1)
+        if np.any(reads[:, :s][join] != 1):
+            _fail("n_rows", "a join row is not read by exactly one slot")
     # topological waves: every gathered producer sits on a strictly
     # earlier wave, and depth covers the deepest chain
     if b and s:
@@ -242,11 +264,11 @@ def lint_batch(batch: lowering.ScenarioBatch) -> None:
         if np.any(bad):
             _fail("wave", "in-order edge does not increase the wave index")
         pw = flat[batch.pred + row[:, None, None]]
-        bad = valid[:, :, None] & real & (pw >= wave[:, :, None])
+        bad = live[:, :, None] & real & (pw >= wave[:, :, None])
         if np.any(bad):
             _fail("wave", "dependency edge does not increase the wave "
                           "index")
-        need = int(wave[valid].max(initial=-1)) + 1
+        need = int(wave[live].max(initial=-1)) + 1
         if batch.depth < need:
             _fail("depth", f"depth {batch.depth} < deepest wave chain "
                            f"{need} (fixpoint not reached)")
@@ -266,11 +288,13 @@ def lint_population_arrays(pa: lowering.PopulationArrays) -> None:
     """The pre-launch check for ``sim_relax_pop`` / ``sched_score``
     decode gathers: the topological permutation and the pred-position
     indices are what the device kernel trusts blindly."""
-    s, c, p = pa.n_subtasks, pa.n_cores, pa.max_preds
+    s, c, p = pa.n_rows, pa.n_cores, pa.max_preds
     _check_int("topo_sid", pa.topo_sid)
     check_shape("topo_sid", pa.topo_sid, (s,))
-    if sorted(pa.topo_sid.tolist()) != list(range(s)):
-        _fail("topo_sid", "not a permutation of the subtasks")
+    sids = pa.topo_sid[pa.topo_sid != -1]
+    if sorted(sids.tolist()) != list(range(pa.n_subtasks)):
+        _fail("topo_sid", "not a permutation of the subtasks (join "
+                          "rows -1)")
     _check_int("gene", pa.gene)
     check_shape("gene", pa.gene, (s,))
     if s and (pa.gene.min() < 0 or pa.gene.max() >= pa.n_tasks):

@@ -11,7 +11,7 @@ everything-at-once form the rest of the system can build on:
   (``overlap``, ``precedence``, ``comm``, ``release``, ``namespace``,
   ``duration``, ``core-range``, ``task-coherence``, ``structure``,
   ``transaction``, ``finite-end``, ``fault``, ``makespan``,
-  ``padding``) — mutation tests assert the verifier *names* the class
+  ``padding``, ``join``) — mutation tests assert the verifier *names* the class
   of corruption, not merely that it throws;
 * checks run to completion and report together (:class:`VerifyError`
   carries them all), so one pass over a corrupted timeline is a full
@@ -45,7 +45,7 @@ from ..core.schedule import ScheduleError
 #: the closed set of violation kinds the verifier emits
 KINDS = ("namespace", "core-range", "duration", "overlap", "precedence",
          "comm", "release", "task-coherence", "structure", "transaction",
-         "finite-end", "fault", "makespan", "padding")
+         "finite-end", "fault", "makespan", "padding", "join")
 
 
 @dataclass(frozen=True)
@@ -346,6 +346,8 @@ def verify_batch_result(batch, result, *, duration=None,
     loop, so proof-checking a device sweep costs a handful of gathers:
 
     * padded slots untouched (exact zeros);
+    * each join row ends at the latest lagged end of the edges it
+      carries (under faults, between the ends the factors' bounds give);
     * finite ends everywhere on fault-free batches;
     * every end >= release floor + duration;
     * the in-order core edge (``batch.prev``) and every dependency
@@ -373,15 +375,16 @@ def verify_batch_result(batch, result, *, duration=None,
             f"subtask_end shape {end.shape} != (B, S) = {(b, s)}"))
         return _finish(out, collect)
     valid = batch.valid
+    live = batch.live
 
     def tol(bound):
         return rtol * np.maximum(1.0, np.abs(bound))
 
-    if np.any(end[~valid] != 0.0):
+    if np.any(end[~live] != 0.0):
         out.append(Violation(
             "padding",
             f"padded slots carry nonzero ends at "
-            f"{_first_bad((end != 0.0) & ~valid)}"))
+            f"{_first_bad((end != 0.0) & ~live)}"))
 
     if batch.has_faults:
         # sound lower bounds: factors can only be >= these
@@ -390,7 +393,7 @@ def verify_batch_result(batch, result, *, duration=None,
     else:
         sf = 1.0
         lf = 1.0
-        bad = valid & ~np.isfinite(end)
+        bad = live & ~np.isfinite(end)
         if np.any(bad):
             out.append(Violation(
                 "finite-end",
@@ -454,6 +457,26 @@ def verify_batch_result(batch, result, *, duration=None,
             "fault",
             f"finite ends consuming a stranded producer at "
             f"{_first_bad(bad)}"))
+
+    join = live & ~valid
+    if np.any(join):
+        # a join row is the max of its edges' lagged ends; degrade
+        # factors lie between the products of their parts below and
+        # above 1
+        lag = np.where(real, batch.pred_lat + batch.pred_volbw, 0.0)
+        hf = np.maximum(batch.deg_f, 1.0).prod(axis=3) \
+            if batch.has_faults else 1.0
+        lo = np.where(real, pred_end + lag * lf, -np.inf).max(axis=2)
+        hi = np.where(real, pred_end + lag * hf, -np.inf).max(axis=2)
+        fin = np.isfinite(lo)
+        with np.errstate(invalid="ignore"):     # -inf bounds off the joins
+            off = (end + tol(lo) < lo) | (end > hi + tol(hi))
+        bad = join & ((fin != finite) | (fin & off))
+        if np.any(bad):
+            out.append(Violation(
+                "join",
+                f"join rows do not end at the latest lagged end of "
+                f"their edges at {_first_bad(bad)}"))
 
     if batch.has_faults:
         bad = valid & finite & (end > batch.fail_t + tol(batch.fail_t))

@@ -25,7 +25,14 @@ gather from:
   ``(B, S, P)`` for the batched relaxation step (``kernels/sim_step.py``
   is the accelerator form of the same step). Scenarios may mix machines
   and graphs freely — the lowering already resolved everything to
-  per-edge lags, so core counts never appear in the batch.
+  per-edge lags, so core counts never appear in the batch;
+* :class:`PredLayout` — the bounded predecessor layout both padded
+  forms (the batch and :class:`PopulationArrays`) share: no row carries
+  more than :data:`ROW_COLUMNS` columns, the in-order column included,
+  and a subtask with more predecessors reads them through a small tree
+  of *join rows* (zero duration, no core, no in-order edge). Max is
+  associative and a join slot's lags are 0, so ends are bit-identical
+  to an unbounded layout.
 
 All arrays are frozen (``writeable=False``): consumers share them.
 """
@@ -387,12 +394,16 @@ class ScenarioBatch:
     """Scenarios padded to one shape. ``pad`` (== S) is the sentinel
     index: gather targets for missing predecessors / first-on-core
     subtasks point at an always-zero slot, and their lags are -inf so
-    they never win the readiness max."""
+    they never win the readiness max. Rows ``n_sub..n_rows-1`` of a
+    scenario are the join rows of its :class:`PredLayout`: zero
+    duration and release, no in-order edge, never failing; each ends at
+    the latest lagged end of the edges it carries."""
 
     n_scenarios: int
-    max_subtasks: int               # S (padded)
-    max_preds: int                  # P (>= 1)
+    max_subtasks: int               # S (padded rows, join rows included)
+    max_preds: int                  # P (>= 1, <= ROW_COLUMNS - 1 when joined)
     n_sub: np.ndarray               # (B,)      int32 — valid subtask count
+    n_rows: np.ndarray              # (B,)      int32 — subtasks + join rows
     duration: np.ndarray            # (B, S)    f64 — exec on assigned core
     release: np.ndarray             # (B, S)    f64
     prev: np.ndarray                # (B, S)    int64 — in-order edge, S = none
@@ -416,48 +427,148 @@ class ScenarioBatch:
 
     @property
     def valid(self) -> np.ndarray:
-        """(B, S) bool mask of real (non-padded) subtasks."""
+        """(B, S) bool mask of real subtasks (not join rows, not pads)."""
         return np.arange(self.max_subtasks)[None, :] < self.n_sub[:, None]
 
-
-def _graph_wave_views(ga: GraphArrays) -> tuple[list[list[int]], list[int]]:
-    """(succ lists, pred counts) of the *graph's* dependency edges,
-    cached on the frozen GraphArrays: they are shared by every scenario
-    of the graph (a B-candidate mapping-search population pays them
-    once, not B times); only the in-order core edge is per-scenario."""
-    v = ga.__dict__.get("_wave_views")
-    if v is None:
-        n = ga.n_subtasks
-        sp = ga.succ_ptr.tolist()
-        ss = ga.succ_sid.tolist()
-        pp = ga.pred_ptr.tolist()
-        v = ([ss[sp[s]:sp[s + 1]] for s in range(n)],
-             [pp[s + 1] - pp[s] for s in range(n)])
-        object.__setattr__(ga, "_wave_views", v)
-    return v
+    @property
+    def live(self) -> np.ndarray:
+        """(B, S) bool mask of rows the relaxation computes: real
+        subtasks and join rows."""
+        return np.arange(self.max_subtasks)[None, :] < self.n_rows[:, None]
 
 
-def _scenario_waves(sa: ScenarioArrays, prev: np.ndarray) -> list[int]:
-    """Per-subtask topological level over deps ∪ in-order edges (the
-    longest path from a source, in subtasks, minus one). Wave ``w``
-    subtasks depend only on waves ``< w``, so one wave-ordered pass —
-    or ``max(wave) + 1`` synchronous sweeps — reaches the fixpoint.
-    Pure-Python Kahn walk: list indexing here is hot at batch-build
-    time and ~10x cheaper than NumPy scalar ops. The graph's adjacency
-    rides in from the GraphArrays cache; the scenario's in-order edge
-    is the ``next_on_core`` inverse of ``prev``."""
-    n = sa.graph.n_subtasks
+#: widest row of a padded predecessor layout, the in-order column
+#: included: edge blocks of the relaxation kernel are then at most
+#: (32, 8, 128), a multiple of the 8-sublane tile. The §5.1 class fits
+#: (27 predecessors at most in ``paper_suite_64core``); wider joins get
+#: join rows.
+ROW_COLUMNS = 32
+
+
+@dataclass(frozen=True)
+class PredLayout:
+    """Where each predecessor edge of one graph sits in a bounded layout.
+
+    Rows ``0..S-1`` are the subtasks, rows ``S..S+J-1`` the join rows.
+    A subtask with more than ``ROW_COLUMNS - 1`` predecessors has its
+    edges dealt, in CSR order, into join rows of ``ROW_COLUMNS - 1``
+    columns, level by level, until the references left fit its own row.
+    A join row carries its edges with the lags they have to the real
+    consumer's core, and its parent reads it through a slot with zero
+    lags. Which edge goes where depends only on the graph, so the layout
+    is built once per graph; only the lags are per scenario. A graph
+    with no wide row lays out exactly as an unbounded padding would."""
+
+    n_subtasks: int                 # S
+    width: int                      # P: widest row, in-order column excluded
+    edge_row: np.ndarray            # (E,) int64 — row of CSR edge e
+    edge_col: np.ndarray            # (E,) int64 — its column there
+    join_row: np.ndarray            # (J,) int64 — row that reads join j
+    join_col: np.ndarray            # (J,) int64 — its column there
+    join_consumer: np.ndarray       # (J,) int64 — real subtask join j feeds
+    succs: list                     # per row: rows it feeds (wave walk)
+    pred_count: list                # per row: filled predecessor columns
+
+    @property
+    def n_joins(self) -> int:
+        return len(self.join_row)
+
+    @property
+    def n_rows(self) -> int:
+        return self.n_subtasks + self.n_joins
+
+
+def _build_layout(ga: GraphArrays) -> PredLayout:
+    cap = ROW_COLUMNS - 1
+    n = ga.n_subtasks
+    ptr = ga.pred_ptr.astype(np.int64)
+    counts = ptr[1:] - ptr[:-1]
+    edge_row = np.repeat(np.arange(n), counts)
+    edge_col = np.arange(len(ga.pred_sid)) - np.repeat(ptr[:-1], counts)
+    join_row: list[int] = []
+    join_col: list[int] = []
+    join_consumer: list[int] = []
+
+    def put(item: int, row: int, col: int) -> None:
+        # item >= 0 is a CSR edge, ~j is join row j
+        if item >= 0:
+            edge_row[item], edge_col[item] = row, col
+        else:
+            join_row[~item], join_col[~item] = row, col
+
+    def fold(s: int) -> None:
+        items = list(range(int(ptr[s]), int(ptr[s + 1])))
+        while len(items) > cap:
+            refs = []
+            for k in range(0, len(items), cap):
+                j = len(join_row)
+                join_row.append(-1)
+                join_col.append(-1)
+                join_consumer.append(s)
+                for c, item in enumerate(items[k:k + cap]):
+                    put(item, n + j, c)
+                refs.append(~j)
+            items = refs
+        for c, item in enumerate(items):
+            put(item, s, c)
+
+    wide = np.flatnonzero(counts > cap).tolist()
+    if wide:
+        with obs.span("lower.join"):
+            for s in wide:
+                fold(s)
+    rows = n + len(join_row)
+    succs: list[list[int]] = [[] for _ in range(rows)]
+    pred_count = [0] * rows
+    for p, r in zip(ga.pred_sid.tolist(), edge_row.tolist()):
+        succs[p].append(r)
+        pred_count[r] += 1
+    for j, r in enumerate(join_row):
+        succs[n + j].append(r)
+        pred_count[r] += 1
+    return PredLayout(
+        n_subtasks=n, width=max([1, *pred_count]),
+        edge_row=_frozen(edge_row), edge_col=_frozen(edge_col),
+        join_row=_frozen(np.asarray(join_row, np.int64)),
+        join_col=_frozen(np.asarray(join_col, np.int64)),
+        join_consumer=_frozen(np.asarray(join_consumer, np.int64)),
+        succs=succs, pred_count=pred_count)
+
+
+def pred_layout(ga: GraphArrays) -> PredLayout:
+    """The graph's bounded predecessor layout, cached on the frozen
+    GraphArrays (every scenario and every search candidate of the graph
+    shares it)."""
+    lay = ga.__dict__.get("_pred_layout")
+    if lay is None:
+        lay = _build_layout(ga)
+        object.__setattr__(ga, "_pred_layout", lay)
+    return lay
+
+
+def _scenario_waves(sa: ScenarioArrays, prev: np.ndarray,
+                    lay: PredLayout) -> list[int]:
+    """Per-row topological level over deps ∪ in-order edges (the
+    longest path from a source, in rows, minus one), join rows
+    included. Wave ``w`` rows depend only on waves ``< w``, so one
+    wave-ordered pass — or ``max(wave) + 1`` synchronous sweeps —
+    reaches the fixpoint. Pure-Python Kahn walk: list indexing here is
+    hot at batch-build time and ~10x cheaper than NumPy scalar ops. The
+    graph's adjacency rides in from the layout cache; the scenario's
+    in-order edge is the ``next_on_core`` inverse of ``prev``."""
+    n = lay.n_subtasks
     if n == 0:
         return []
-    succs, pred_count = _graph_wave_views(sa.graph)
+    succs, pred_count = lay.succs, lay.pred_count
     prev_l = prev.tolist()
     nxt = [-1] * n
+    indeg = list(pred_count)
     for s, p in enumerate(prev_l):
         if p >= 0:
             nxt[p] = s
-    indeg = [c + (prev_l[s] >= 0) for s, c in enumerate(pred_count)]
-    wave = [0] * n
-    stack = [s for s in range(n) if indeg[s] == 0]
+            indeg[s] += 1
+    wave = [0] * len(indeg)
+    stack = [s for s, d in enumerate(indeg) if d == 0]
     seen = 0
     while stack:
         s = stack.pop()
@@ -469,29 +580,30 @@ def _scenario_waves(sa: ScenarioArrays, prev: np.ndarray) -> list[int]:
             indeg[t] -= 1
             if indeg[t] == 0:
                 stack.append(t)
-        t = nxt[s]
+        t = nxt[s] if s < n else -1
         if t >= 0:
             if wave[t] < w1:
                 wave[t] = w1
             indeg[t] -= 1
             if indeg[t] == 0:
                 stack.append(t)
-    assert seen == n, "scenario dependency graph has a cycle"
+    assert seen == len(indeg), "scenario dependency graph has a cycle"
     return wave
 
 
 def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
     """Pad scenarios (possibly of different graphs AND machines) to one
     fixed-shape batch for :func:`repro.core.sim_engine.relax_batch_np`
-    / the ``sim_step`` kernel."""
+    / the ``sim_step`` kernel, each graph in its :class:`PredLayout`."""
     if not scenarios:
         raise ValueError("batch_scenarios needs at least one scenario")
     b = len(scenarios)
-    s_max = max(sa.graph.n_subtasks for sa in scenarios)
-    p_max = max(1, max(int((sa.graph.pred_ptr[1:] - sa.graph.pred_ptr[:-1])
-                           .max(initial=0)) for sa in scenarios))
+    layouts = [pred_layout(sa.graph) for sa in scenarios]
+    s_max = max(lay.n_rows for lay in layouts)
+    p_max = max(lay.width for lay in layouts)
     pad = s_max
     n_sub = np.zeros(b, np.int32)
+    n_rows = np.zeros(b, np.int32)
     duration = np.zeros((b, s_max))
     release = np.zeros((b, s_max))
     prev = np.full((b, s_max), pad, np.int64)
@@ -501,6 +613,7 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
     wave = np.zeros((b, s_max), np.int32)
     t_est = np.zeros(b)
     depth = 0
+    n_edges = 0
     faulty = [sa.fault for sa in scenarios]
     has_faults = any(f is not None for f in faulty)
     k_slow = max((f.max_slow_events for f in faulty if f is not None),
@@ -513,9 +626,10 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
         slow_f = np.ones((b, s_max, k_slow))
         deg_t = np.full((b, s_max, p_max, k_deg), np.inf)
         deg_f = np.ones((b, s_max, p_max, k_deg))
-    for i, sa in enumerate(scenarios):
+    for i, (sa, lay) in enumerate(zip(scenarios, layouts)):
         n = sa.graph.n_subtasks
         n_sub[i] = n
+        n_rows[i] = lay.n_rows
         if n == 0:
             continue
         duration[i, :n] = sa.duration()
@@ -527,8 +641,7 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
             sa.graph.pred_vol
         counts = (ptr[1:] - ptr[:-1]).astype(np.int64)
         dst = np.repeat(np.arange(n), counts)       # edge -> consumer sid
-        col = np.arange(len(psid)) - np.repeat(ptr[:-1].astype(np.int64),
-                                               counts)
+        row, col = lay.edge_row, lay.edge_col       # edge -> its slot
         cp = sa.core_of[psid]
         cs = sa.core_of[dst]
         # same-core / volume-free edges arrive instantly (no latency),
@@ -536,9 +649,10 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
         # is an exact 0.0 there already
         lag_lat = np.where(pvol <= 0.0, 0.0, sa.machine.lat[cp, cs])
         lag_volbw = np.where(pvol <= 0.0, 0.0, pvol / sa.machine.bw[cp, cs])
-        pred[i, dst, col] = psid
-        pred_lat[i, dst, col] = lag_lat
-        pred_volbw[i, dst, col] = lag_volbw
+        pred[i, row, col] = psid
+        pred_lat[i, row, col] = lag_lat
+        pred_volbw[i, row, col] = lag_volbw
+        n_edges += len(psid) + int(has_prev.sum())
         if sa.fault is not None:
             fl = sa.fault
             fail_t[i, :n] = fl.fail_t[sa.core_of]
@@ -555,19 +669,33 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
                         continue
                     steps = fl.degrade.get((min(a, c2), max(a, c2)))
                     for k, (t, f) in enumerate(steps or ()):
-                        deg_t[i, dst[e], col[e], k] = t
-                        deg_f[i, dst[e], col[e], k] = f
-        waves_i = _scenario_waves(sa, prev_i)
-        wave[i, :n] = waves_i
+                        deg_t[i, row[e], col[e], k] = t
+                        deg_f[i, row[e], col[e], k] = f
+        waves_i = _scenario_waves(sa, prev_i, lay)
+        wave[i, :lay.n_rows] = waves_i
         t_est[i] = sa.t_est
         depth = max(depth, max(waves_i) + 1 if waves_i else 0)
+    n_joins = int((n_rows - n_sub).sum())
+    if n_joins:
+        # each join row is read through a slot with zero lags
+        with obs.span("lower.join"):
+            for i, lay in enumerate(layouts):
+                if lay.n_joins:
+                    jr, jc = lay.join_row, lay.join_col
+                    pred[i, jr, jc] = lay.n_subtasks + np.arange(lay.n_joins)
+                    pred_lat[i, jr, jc] = 0.0
+                    pred_volbw[i, jr, jc] = 0.0
+    obs.count("lower.join_rows", n_joins)
+    obs.count("lower.edge_slots", b * s_max * (p_max + 1))
+    obs.count("lower.edges", n_edges)
     fault_fields = {} if not has_faults else {
         "fail_t": _frozen(fail_t), "slow_t": _frozen(slow_t),
         "slow_f": _frozen(slow_f), "deg_t": _frozen(deg_t),
         "deg_f": _frozen(deg_f)}
     return ScenarioBatch(
         n_scenarios=b, max_subtasks=s_max, max_preds=p_max,
-        n_sub=_frozen(n_sub), duration=_frozen(duration),
+        n_sub=_frozen(n_sub), n_rows=_frozen(n_rows),
+        duration=_frozen(duration),
         release=_frozen(release), prev=_frozen(prev), pred=_frozen(pred),
         pred_lat=_frozen(pred_lat), pred_volbw=_frozen(pred_volbw),
         wave=_frozen(wave), t_est=_frozen(t_est), depth=depth,
@@ -604,7 +732,7 @@ def repeat_batch(batch: ScenarioBatch, k: int) -> ScenarioBatch:
     the batch construction again."""
     if k <= 1:
         return batch
-    fields = ["n_sub", "duration", "release", "prev", "pred",
+    fields = ["n_sub", "n_rows", "duration", "release", "prev", "pred",
               "pred_lat", "pred_volbw", "wave", "t_est"]
     if batch.has_faults:
         fields += ["fail_t", "slow_t", "slow_f", "deg_t", "deg_f"]
@@ -627,10 +755,18 @@ class PopulationArrays:
     population fitness: everything a genome needs to decode into finish
     times is resolved to fixed-shape arrays in one fixed topological
     order, so a whole GA generation is pure gathers + one scan — no
-    per-candidate re-lowering, ever. All per-subtask arrays live in
+    per-candidate re-lowering, ever. All per-row arrays live in
     **topological-position coordinates** (``topo_sid`` maps back to
     sids); predecessor slots are padded to ``max_preds`` with the
-    sentinel position ``S`` (an always-zero end slot).
+    sentinel position ``n_rows`` (an always-zero end slot).
+
+    The rows are the graph's :class:`PredLayout`: each join row sits
+    right before the subtask it feeds and takes that subtask's gene, so
+    its edges' lags are to the consumer's core, and its own slot in the
+    consumer has zero volume. Its zero duration and the consumer coming
+    next make the core frontier it passes on (the scan's carry, the
+    kernel's in-order edge) one the consumer reads anyway, so the
+    consumer's end is exactly the unbounded layout's.
 
     Built once per (graph, machine) pair and cached on the graph — the
     population axis exists only on device, this object is candidate-free.
@@ -638,14 +774,15 @@ class PopulationArrays:
 
     n_tasks: int
     n_subtasks: int                 # S
+    n_rows: int                     # R = S + join rows
     n_cores: int                    # C
     max_preds: int                  # P (>= 1)
-    topo_sid: np.ndarray            # (S,)   int32 — topo position -> sid
-    gene: np.ndarray                # (S,)   int32 — gene slot of the task
-    exec_core: np.ndarray           # (S, C) f64 — topo-permuted exec times
-    pred_pos: np.ndarray            # (S, P) int32 — pred topo position, S pad
-    pred_gene: np.ndarray           # (S, P) int32 — pred's gene slot, 0 pad
-    pred_vol: np.ndarray            # (S, P) f64 — edge volume, 0 pad
+    topo_sid: np.ndarray            # (R,)   int32 — position -> sid, -1 join
+    gene: np.ndarray                # (R,)   int32 — gene slot of the task
+    exec_core: np.ndarray           # (R, C) f64 — topo-permuted exec times
+    pred_pos: np.ndarray            # (R, P) int32 — pred topo position, R pad
+    pred_gene: np.ndarray           # (R, P) int32 — pred's gene slot, 0 pad
+    pred_vol: np.ndarray            # (R, P) f64 — edge volume, 0 pad
     lat: np.ndarray                 # (C, C) f64
     bw: np.ndarray                  # (C, C) f64
 
@@ -669,44 +806,54 @@ def population_arrays(graph: AppGraph, machine: MachineModel
         return cached[2]
     obs.count("lower.population_arrays.miss")
     s = ga.n_subtasks
+    lay = pred_layout(ga)
+    obs.count("lower.join_rows", lay.n_joins)
     indeg = (ga.pred_ptr[1:] - ga.pred_ptr[:-1]).tolist()
     succ_ptr, succ_sid = ga.succ_ptr.tolist(), ga.succ_sid.tolist()
     heap = [i for i in range(s) if indeg[i] == 0]
     heapq.heapify(heap)
-    order: list[int] = []
+    joins_of: list[list[int]] = [[] for _ in range(s)]
+    for j, c in enumerate(lay.join_consumer.tolist()):
+        joins_of[c].append(s + j)           # children before parents
+    order: list[int] = []                   # layout rows by position
     while heap:
         sid = heapq.heappop(heap)
+        order.extend(joins_of[sid])
         order.append(sid)
         for j in range(succ_ptr[sid], succ_ptr[sid + 1]):
             t = succ_sid[j]
             indeg[t] -= 1
             if indeg[t] == 0:
                 heapq.heappush(heap, t)
-    assert len(order) == s, "graph has a cycle"
-    topo_sid = np.asarray(order, np.int32)
-    pos_of = np.zeros(s, np.int64)
-    pos_of[topo_sid] = np.arange(s)
+    assert len(order) == lay.n_rows, "graph has a cycle"
+    r = lay.n_rows
+    rows = np.asarray(order, np.int64)
+    pos_of = np.zeros(r, np.int64)
+    pos_of[rows] = np.arange(r)
+    # a join row stands for its consumer: its gene, no work
+    owner = np.concatenate([np.arange(s), lay.join_consumer])
     gene_of_tid = {tid: k for k, tid in enumerate(graph.tasks)}
     gene_sid = np.asarray([gene_of_tid[st.task_id] for st in graph.subtasks],
                           np.int32) if s else np.zeros(0, np.int32)
-    p_max = max(1, int((ga.pred_ptr[1:] - ga.pred_ptr[:-1]).max(initial=0)))
-    pred_pos = np.full((s, p_max), s, np.int32)
-    pred_gene = np.zeros((s, p_max), np.int32)
-    pred_vol = np.zeros((s, p_max))
-    ptr = ga.pred_ptr
-    for p in range(s):
-        sid = int(topo_sid[p])
-        lo, hi = int(ptr[sid]), int(ptr[sid + 1])
-        k = hi - lo
-        pred_pos[p, :k] = pos_of[ga.pred_sid[lo:hi]]
-        pred_gene[p, :k] = gene_sid[ga.pred_sid[lo:hi]]
-        pred_vol[p, :k] = ga.pred_vol[lo:hi]
+    exec_row = np.concatenate([_exec_core(ga, ma),
+                               np.zeros((lay.n_joins, ma.n_cores))])
+    p_max = lay.width
+    pred_pos = np.full((r, p_max), r, np.int32)
+    pred_gene = np.zeros((r, p_max), np.int32)
+    pred_vol = np.zeros((r, p_max))
+    at = (pos_of[lay.edge_row], lay.edge_col)
+    pred_pos[at] = pos_of[ga.pred_sid]
+    pred_gene[at] = gene_sid[ga.pred_sid]
+    pred_vol[at] = ga.pred_vol
+    at = (pos_of[lay.join_row], lay.join_col)
+    pred_pos[at] = pos_of[s:]
+    pred_gene[at] = gene_sid[lay.join_consumer]
     pa = PopulationArrays(
-        n_tasks=ga.n_tasks, n_subtasks=s, n_cores=ma.n_cores,
+        n_tasks=ga.n_tasks, n_subtasks=s, n_rows=r, n_cores=ma.n_cores,
         max_preds=p_max,
-        topo_sid=_frozen(topo_sid),
-        gene=_frozen(gene_sid[topo_sid] if s else gene_sid),
-        exec_core=_frozen(_exec_core(ga, ma)[topo_sid]),
+        topo_sid=_frozen(np.where(rows < s, rows, -1).astype(np.int32)),
+        gene=_frozen(gene_sid[owner[rows]] if s else gene_sid),
+        exec_core=_frozen(exec_row[rows]),
         pred_pos=_frozen(pred_pos), pred_gene=_frozen(pred_gene),
         pred_vol=_frozen(pred_vol), lat=ma.lat, bw=ma.bw,
     )

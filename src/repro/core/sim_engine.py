@@ -329,8 +329,9 @@ def relax_batch_np(batch: ScenarioBatch, duration: np.ndarray | None = None,
 
 
 def _wave_plan(batch: ScenarioBatch):
-    """Wave-ordered evaluation plan, cached on the batch: every valid
-    (scenario, subtask) pair sorted by topological level, with its
+    """Wave-ordered evaluation plan, cached on the batch: every live
+    (scenario, row) pair — subtasks and join rows — sorted by
+    topological level, with its
     gather sources (preds + in-order edge) resolved to flat indices
     into the ``(B, S+1)`` end buffer and its lags prefolded. Segment
     ``w`` of the plan depends only on segments ``< w``, so one pass
@@ -341,8 +342,8 @@ def _wave_plan(batch: ScenarioBatch):
     b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
     idx, lag = _gather_inputs(batch)
     flat_pos = np.arange(b * s)
-    valid = (flat_pos % s) < batch.n_sub.astype(np.int64)[flat_pos // s]
-    order = flat_pos[valid]
+    live = (flat_pos % s) < batch.n_rows.astype(np.int64)[flat_pos // s]
+    order = flat_pos[live]
     waves = batch.wave.reshape(-1)[order]
     sort = np.argsort(waves, kind="stable")
     order, waves = order[sort], waves[sort]
@@ -457,7 +458,8 @@ class BatchSimResult:
     """Whole-suite simulation outcome (analytic semantics + jitter)."""
 
     t_exec: np.ndarray              # (B,)
-    subtask_end: np.ndarray         # (B, S) padded; invalid slots are 0
+    subtask_end: np.ndarray         # (B, S) padded: subtasks, then join
+    #   rows (each the readiness it folds); pad slots are 0
     t_est: np.ndarray               # (B,) the schedules' makespans
     n_sub: np.ndarray               # (B,)
 
@@ -523,11 +525,11 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
     else:
         raise ValueError(f"unknown backend {backend!r} "
                          "(have 'numpy', 'pallas')")
-    masked = np.where(batch.valid, end, 0.0)
+    masked = np.where(batch.live, end, 0.0)
     # stranded subtasks (faults) carry inf ends: the makespan is over
     # the work that finished, like SimResult under faults
-    t_exec = np.where(np.isfinite(masked), masked, 0.0).max(axis=1,
-                                                            initial=0.0)
+    t_exec = np.where(batch.valid & np.isfinite(masked), masked,
+                      0.0).max(axis=1, initial=0.0)
     result = BatchSimResult(t_exec=t_exec, subtask_end=masked,
                             t_est=batch.t_est, n_sub=batch.n_sub)
     if verify:

@@ -77,6 +77,8 @@ class DevicePopulation(NamedTuple):
 
     @property
     def n_subtasks(self) -> int:
+        """Rows of the layout (``PopulationArrays.n_rows``): the
+        subtasks and their join rows."""
         return self.topo_gene.shape[0]
 
     @property
@@ -103,14 +105,15 @@ def device_inputs(graph: AppGraph, machine: MachineModel, *,
                 raise ValueError(f"release for unknown subtask {sid} "
                                  f"(graph has {pa.n_subtasks})")
             rel[sid] = t
-        rel = rel[pa.topo_sid]
+    # join rows (topo_sid -1) have no floor
+    rel = np.where(pa.topo_sid >= 0, rel[pa.topo_sid], np.float32(0.0))
     return DevicePopulation(
         topo_gene=jnp.asarray(pa.gene),
         exec_core=jnp.asarray(pa.exec_core, jnp.float32),
         pred_pos=jnp.asarray(pa.pred_pos),
         pred_gene=jnp.asarray(pa.pred_gene),
         pred_vol=jnp.asarray(pa.pred_vol, jnp.float32),
-        pred_pad=jnp.asarray(pa.pred_pos == pa.n_subtasks),
+        pred_pad=jnp.asarray(pa.pred_pos == pa.n_rows),
         lat=jnp.asarray(pa.lat, jnp.float32),
         bw=jnp.asarray(pa.bw, jnp.float32),
         release=jnp.asarray(rel),

@@ -313,6 +313,46 @@ def test_batch_detects_nonfinite_end_without_faults():
     assert "finite-end" in ei.value.kinds
 
 
+def join_fixture():
+    """A 4 x 4 Montage: its mConcatFit reads 33 fits through 2 join rows."""
+    from repro.core.workflows import montage
+    m = hp_bl260c()
+    wf = montage(4, 1)
+    sch = get_scheduler("engine")(wf, m)
+    batch = lowering.batch_scenarios([lowering.lower_scenario(wf, m, sch)])
+    res = simulate_batch(batch, verify=True)
+    return batch, res
+
+
+def test_batch_detects_corrupted_join_row():
+    batch, res = join_fixture()
+    j = int(batch.n_sub[0])                 # first join row
+    assert batch.n_rows[0] == j + 2
+    end = np.array(res.subtask_end)
+    end[0, j] *= 0.5                        # below its latest leaf
+    bad = dataclasses.replace(res, subtask_end=end)
+    with pytest.raises(VerifyError) as ei:
+        verify_batch_result(batch, bad)
+    assert ei.value.kinds == {"join"}
+
+
+def test_ir_lint_rejects_a_join_row_with_work_or_no_reader():
+    batch, _ = join_fixture()
+    j, s = int(batch.n_sub[0]), batch.max_subtasks
+    dur = np.array(batch.duration)
+    dur[0, j] = 1.0
+    with pytest.raises(IRLintError, match="n_rows"):
+        lint_batch(dataclasses.replace(batch, duration=dur))
+    # nobody reads the join row: its slot becomes padding
+    pred, lat, volbw = (np.array(x) for x in
+                        (batch.pred, batch.pred_lat, batch.pred_volbw))
+    at = pred == j
+    pred[at], lat[at], volbw[at] = s, -np.inf, -np.inf
+    with pytest.raises(IRLintError, match="n_rows"):
+        lint_batch(dataclasses.replace(batch, pred=pred, pred_lat=lat,
+                                       pred_volbw=volbw))
+
+
 # ---------------------------------------------------------------------------
 # IR linter: lowered-array contract violations
 # ---------------------------------------------------------------------------

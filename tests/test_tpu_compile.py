@@ -65,6 +65,21 @@ def test_sim_relax_pop_compiles(one_chip, p1):
     assert _holds_kernel(compiled)
 
 
+def test_sim_relax_pop_compiles_at_the_join_width(one_chip):
+    """A suite call of four Montage workflows (1223 jobs at most, 41 join
+    rows) x 16 draws: rows of ROW_COLUMNS columns keep each grid cell's
+    edge blocks in VMEM, where a 706-column padding does not fit."""
+    from repro.core.lowering import ROW_COLUMNS
+    from repro.kernels.sim_step import sim_relax_pop
+    b, s = 64, 1264
+    edge = [_spec(one_chip, (b, s, ROW_COLUMNS), dt)
+            for dt in (jnp.int32, jnp.float32, jnp.float32)]
+    node = [_spec(one_chip, (b, s), jnp.float32)] * 2
+    compiled = sim_relax_pop.lower(*edge, *node, n_steps=29,
+                                   interpret=False).compile()
+    assert _holds_kernel(compiled)
+
+
 @pytest.mark.parametrize("apps,cores", [(8, 256), (130, 300)])
 def test_sched_score_compiles(one_chip, apps, cores):
     from repro.kernels.sched_score import sched_score
