@@ -2,8 +2,9 @@
 
     PYTHONPATH=src python -m benchmarks.fitness_timing [--seed N]
 
-Times one fitness call of the Pallas kernel (``ops.sim_relax_pop``, at
-64 sweeps and at the S sweeps the GA runs) and of the fused scan
+Times one fitness call of the Pallas kernel (``ops.sim_relax_pop``,
+bounded at 64 sweeps and at the S sweeps the GA allows; the kernel
+stops earlier at its fixpoint) and of the fused scan
 (``search.device.population_ends``) on one random population of the
 256-core / 1 090-subtask / pop-256 mapping search that ``chip_smoke.py``
 drives, then checks that both give identical ends. Prints one JSON line

@@ -40,7 +40,7 @@ from repro.core import (SynthParams, cluster_of_multicores,
                         hp_bl260c, lower_population, simulate_batch,
                         simulate_scenario, validate)
 from repro.search import (GAParams, decode_population, device_inputs,
-                          ga_schedule, population_fitness_device)
+                          ga_schedule)
 
 
 # ---------------------------------------------------------------------------
@@ -83,7 +83,7 @@ def bench_phases(name: str, machine, params: SynthParams, pop_size: int,
     import jax
     import jax.numpy as jnp
 
-    from repro.search.device import generation_step
+    from repro.search.device import generation_step, score_population
     from repro.search.ga import next_generation
 
     app = generate_app(params, seed)
@@ -111,16 +111,16 @@ def bench_phases(name: str, machine, params: SynthParams, pop_size: int,
 
     inp = device_inputs(app, machine)
     dpop = jnp.asarray(pop)
-    dfit = population_fitness_device(inp, dpop)
+    dfit = score_population(inp, dpop)
     step = generation_step(par, n_tasks=n_tasks, n_cores=machine.n_cores)
     key = jax.random.PRNGKey(seed)
-    step(inp, key, dpop, dfit)[1].block_until_ready()      # jit warm-up
+    step(inp, key, dpop, dfit)[1].fit.block_until_ready()  # jit warm-up
     t0 = time.perf_counter()
     p, f = dpop, dfit
     for i in range(gens):
         key, kg = jax.random.split(key)
         p, f = step(inp, kg, p, f)
-    f.block_until_ready()
+    f.fit.block_until_ready()
     device_gen_s = (time.perf_counter() - t0) / gens
 
     row = {"suite": name, "pop": pop_size, "tasks": n_tasks,

@@ -166,7 +166,8 @@ def phase_search(seed: int, *, n_blades: int = 32,
     from repro.kernels import ops
     from repro.kernels import sim_step
     from repro.search import GAParams, ga_schedule
-    from repro.search.device import (device_inputs, generation_step,
+    from repro.search.device import (Fitness, device_inputs,
+                                     generation_step,
                                      population_gather_inputs)
 
     machine = cluster_of_multicores(n_blades)
@@ -197,7 +198,8 @@ def phase_search(seed: int, *, n_blades: int = 32,
     np.testing.assert_allclose(got[:rows], ref, rtol=1e-5)
     diff = float(np.max(np.abs(got[:rows] - ref)))
 
-    fit = jnp.max(jnp.asarray(got), axis=1)
+    zero = jnp.zeros((), jnp.int32)
+    fit = Fitness(jnp.max(jnp.asarray(got), axis=1), zero, zero)
     step = generation_step(params, n_tasks=len(graph.tasks),
                            n_cores=machine.n_cores, method="kernel")
     checks = [

@@ -145,7 +145,7 @@ def _build_generation_step(suite: str) -> Built:
     import jax.numpy as jnp
 
     from ..search.device import (device_inputs, generation_step,
-                                 population_fitness_device)
+                                 score_population)
     from ..search.ga import GAParams
     machine, graphs = _suite_workload(suite)
     graph = graphs[0]
@@ -159,7 +159,7 @@ def _build_generation_step(suite: str) -> Built:
         k = jax.random.PRNGKey(seed)
         pop = jax.random.randint(k, (params.pop_size, n_tasks), 0,
                                  machine.n_cores, jnp.int32)
-        return (inp, k, pop, population_fitness_device(inp, pop))
+        return (inp, k, pop, score_population(inp, pop))
 
     return Built(fn=step, jfn=step, args=pop_at(0),
                  sweep=(pop_at(1), pop_at(2)))

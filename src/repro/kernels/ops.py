@@ -6,13 +6,14 @@ may rely on it, and ``chip_smoke.py`` fails when a kernel's compiled
 program holds no ``tpu_custom_call``.
 
 The scheduling kernels (``sched_score`` / ``sim_step`` / ``sim_relax`` /
-``sim_relax_pop``) gather through caller-provided index arrays; an
-out-of-bounds index does not crash on device, it clamps and reads the
-wrong slot, returning a plausible wrong score. Their wrappers therefore
-run the tracer-safe checks from :mod:`repro.analysis.ir_lint` before
-launch: shapes always (static metadata even under ``jax.jit`` tracing —
-the device GA calls ``sim_relax_pop`` inside its jitted generation
-step), index-range checks whenever the operands are concrete."""
+``sim_relax_pop`` / ``sim_relax_pop_sweeps``) gather through
+caller-provided index arrays; an out-of-bounds index does not crash on
+device, it clamps and reads the wrong slot, returning a plausible wrong
+score. Their wrappers therefore run the tracer-safe checks from
+:mod:`repro.analysis.ir_lint` before launch: shapes always (static
+metadata even under ``jax.jit`` tracing — the device GA calls
+``sim_relax_pop_sweeps`` inside its jitted generation step),
+index-range checks whenever the operands are concrete."""
 
 from __future__ import annotations
 
@@ -89,17 +90,31 @@ def sim_relax(lat, volbw, duration, release, *, n_steps, sub_block=128):
                           sub_block=sub_block, interpret=not _on_tpu())
 
 
-def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps):
+def _check_relax_pop(pred, lat, volbw, duration, release, name):
     b, s, p1 = pred.shape
-    check_shape("sim_relax_pop.lat", lat, (b, s, p1))
-    check_shape("sim_relax_pop.volbw", volbw, (b, s, p1))
-    check_shape("sim_relax_pop.duration", duration, (b, s))
-    check_shape("sim_relax_pop.release", release, (b, s))
+    check_shape(f"{name}.lat", lat, (b, s, p1))
+    check_shape(f"{name}.volbw", volbw, (b, s, p1))
+    check_shape(f"{name}.duration", duration, (b, s))
+    check_shape(f"{name}.release", release, (b, s))
+
+
+def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps):
+    _check_relax_pop(pred, lat, volbw, duration, release, "sim_relax_pop")
     # the kernel gathers end[pred] from an (S+1)-slot buffer whose last
     # slot is the zero sentinel; anything past it reads garbage
-    check_gather_bounds(pred, s, "sim_relax_pop.pred")
+    check_gather_bounds(pred, pred.shape[1], "sim_relax_pop.pred")
     return _sim.sim_relax_pop(pred, lat, volbw, duration, release,
                               n_steps=n_steps, interpret=not _on_tpu())
+
+
+def sim_relax_pop_sweeps(pred, lat, volbw, duration, release, *, n_steps):
+    """``(ends, sweeps)``: :func:`sim_relax_pop` and the sweeps it ran."""
+    _check_relax_pop(pred, lat, volbw, duration, release,
+                     "sim_relax_pop_sweeps")
+    check_gather_bounds(pred, pred.shape[1], "sim_relax_pop_sweeps.pred")
+    return _sim.sim_relax_pop_sweeps(pred, lat, volbw, duration, release,
+                                     n_steps=n_steps,
+                                     interpret=not _on_tpu())
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, scale=None, softcap=None,

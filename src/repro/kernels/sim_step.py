@@ -19,7 +19,9 @@ The max-plus form is deliberately kernel-friendly: per grid cell one
 VMEM tile of each lag tensor, a broadcast row of the current ends, an
 elementwise add-add-max reduction along the lane axis — no gathers, no
 cross-tile reductions. ``sim_relax`` iterates the step to the batch's
-fixpoint depth under one ``jit``. The NumPy oracle ``sim_step_np`` is
+fixpoint depth under one ``jit``; the population variant
+``sim_relax_pop`` stops its sweeps at the fixpoint itself, with
+``n_steps`` as the bound. The NumPy oracle ``sim_step_np`` is
 the allclose target (re-exported as ``kernels.ref.sim_step_ref``); the
 float64 production path on CPU is the padded-CSR relaxation in
 ``repro.core.sim_engine.relax_batch_np`` — tests sweep all three
@@ -203,22 +205,14 @@ def _pop_step_kernel(end_ref, pred_ref, lat_ref, volbw_ref, dur_ref,
                                             jnp.maximum(ready, 0.0))
 
 
-@functools.partial(jax.jit, static_argnames=("n_steps", "interpret"))
-def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps: int,
-                  interpret: bool = False):
-    """Iterate the sparse population sweep ``n_steps`` times from zeros.
-
-    Inputs are the padded-CSR gather form: ``pred`` (B, S, P) int32
-    sources with sentinel ``S``, ``lat``/``volbw`` (B, S, P) per-edge
-    lags (``-inf`` pads), ``duration``/``release`` (B, S). The padded
-    end buffer keeps one extra 128-aligned region whose rows evaluate
-    to exactly 0 every sweep (0 duration, 0 release, all-(-inf) lags),
-    so the sentinel slot needs no special handling inside the kernel.
-    Returns (B, S) float32 finish times.
-
-    The per-edge operands are laid out (P, B, S), so every block is
-    (P, 8, 128) or (8, 128): the TPU tiling. B is padded to a multiple
-    of 8 with rows that stay 0."""
+def _relax_pop(pred, lat, volbw, duration, release, n_steps: int,
+               interpret: bool):
+    """(ends, sweeps): the sweeps of :func:`sim_relax_pop` and how many
+    ran. A sweep is a pure function of the end buffer, so the first
+    sweep that leaves the whole padded buffer bitwise unchanged proves
+    every later one would too: the loop stops there (that sweep
+    counted) or after ``n_steps`` sweeps, whichever comes first, and
+    returns bit-for-bit what ``n_steps`` sweeps return."""
     pred = jnp.asarray(pred, jnp.int32)
     lat = jnp.asarray(lat, jnp.float32)
     volbw = jnp.asarray(volbw, jnp.float32)
@@ -246,8 +240,51 @@ def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps: int,
         out_shape=jax.ShapeDtypeStruct((bp, sp), jnp.float32),
         interpret=interpret,
     )
-    end = jax.lax.fori_loop(
-        0, n_steps,
-        lambda _, e: call(e, pred, lat, volbw, duration, release),
-        jnp.zeros((bp, sp), jnp.float32))
-    return end[:b, :s]
+
+    def sweep(carry):
+        end, _, k = carry
+        new = call(end, pred, lat, volbw, duration, release)
+        bits = functools.partial(jax.lax.bitcast_convert_type,
+                                 new_dtype=jnp.int32)
+        return new, jnp.any(bits(new) != bits(end)), k + 1
+
+    end, _, sweeps = jax.lax.while_loop(
+        lambda c: c[1] & (c[2] < n_steps), sweep,
+        (jnp.zeros((bp, sp), jnp.float32), jnp.array(True),
+         jnp.zeros((), jnp.int32)))
+    return end[:b, :s], sweeps
+
+
+@functools.partial(jax.jit, static_argnames=("n_steps", "interpret"))
+def sim_relax_pop(pred, lat, volbw, duration, release, *, n_steps: int,
+                  interpret: bool = False):
+    """Iterate the sparse population sweep from zeros to its fixpoint,
+    at most ``n_steps`` sweeps.
+
+    Inputs are the padded-CSR gather form: ``pred`` (B, S, P) int32
+    sources with sentinel ``S``, ``lat``/``volbw`` (B, S, P) per-edge
+    lags (``-inf`` pads), ``duration``/``release`` (B, S). The padded
+    end buffer keeps one extra 128-aligned region whose rows evaluate
+    to exactly 0 every sweep (0 duration, 0 release, all-(-inf) lags),
+    so the sentinel slot needs no special handling inside the kernel.
+    Returns (B, S) float32 finish times, bit-for-bit those of
+    ``n_steps`` sweeps: the loop stops at the first sweep that changes
+    nothing. An acyclic batch gets there within its longest path plus
+    one, so the longest path (or S) as ``n_steps`` is always enough.
+
+    The per-edge operands are laid out (P, B, S), so every block is
+    (P, 8, 128) or (8, 128): the TPU tiling. B is padded to a multiple
+    of 8 with rows that stay 0."""
+    return _relax_pop(pred, lat, volbw, duration, release, n_steps,
+                      interpret)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("n_steps", "interpret"))
+def sim_relax_pop_sweeps(pred, lat, volbw, duration, release, *,
+                         n_steps: int, interpret: bool = False):
+    """:func:`sim_relax_pop` with the number of sweeps it ran: returns
+    ``(ends, sweeps)``, ``sweeps`` an int32 device scalar in
+    ``[0, n_steps]`` that counts the last sweep, the one that changed
+    nothing, unless the bound stopped the loop first."""
+    return _relax_pop(pred, lat, volbw, duration, release, n_steps,
+                      interpret)

@@ -22,9 +22,14 @@ the whole generation on device:
   population in a single XLA computation. Alternatively
   (``method="kernel"``, the default on TPU) the same recurrence runs
   as synchronous max-plus sweeps through the population-axis Pallas
-  kernel ``kernels/sim_step.sim_relax_pop`` — acyclic, so both reach
-  the identical fixpoint bit-for-bit (``kernels.ref.sim_relax_pop_ref``
-  is the NumPy oracle, pinned by ``tests/test_search.py``).
+  kernel ``kernels/sim_step.sim_relax_pop_sweeps``, which stops at the
+  fixpoint with S sweeps as the bound — acyclic, so both reach the
+  identical fixpoint bit-for-bit (``kernels.ref.sim_relax_pop_ref`` is
+  the NumPy oracle, pinned by ``tests/test_search.py``). Each kernel
+  call's sweep count stays on the device in the population's
+  :class:`Fitness` and is read where the search reads its best row
+  anyway: the counters ``relax.sweeps``, ``relax.sweep_bound`` and
+  ``relax.calls`` (``repro.obs``) sum them over a search.
 * **Selection on device** (:func:`ga_search_device`). Tournament +
   elite-bias parent draws, uniform crossover and gene resampling are
   jitted ``jax.random`` array ops under one threaded PRNG key — no
@@ -218,14 +223,21 @@ def population_ends(inp: DevicePopulation, genes) -> jnp.ndarray:
     )(core, dur, lag_lat, lag_volbw)
 
 
-def population_ends_kernel(inp: DevicePopulation, genes) -> jnp.ndarray:
+def population_ends_kernel(inp: DevicePopulation, genes, *,
+                           sweeps: list | None = None) -> jnp.ndarray:
     """(B, S) finish times via the population-axis Pallas kernel
-    (``kernels/sim_step.sim_relax_pop``): S synchronous max-plus sweeps
-    reach the same acyclic fixpoint as the scan, bit-for-bit."""
+    (``kernels/sim_step.sim_relax_pop_sweeps``): synchronous max-plus
+    sweeps from zeros, stopped at the fixpoint with S sweeps as the
+    bound, reach the same acyclic fixpoint as the scan, bit-for-bit.
+    The call appends its sweep count (an int32 device scalar) to
+    ``sweeps`` when given."""
     from ..kernels import ops
     pred, lat, volbw, dur, rel = _prepare_kernel_inputs(inp, genes)
-    return ops.sim_relax_pop(pred, lat, volbw, dur, rel,
-                             n_steps=inp.n_subtasks)
+    ends, ran = ops.sim_relax_pop_sweeps(pred, lat, volbw, dur, rel,
+                                         n_steps=inp.n_subtasks)
+    if sweeps is not None:
+        sweeps.append(ran)
+    return ends
 
 
 _prepare_kernel_inputs = jax.jit(population_gather_inputs)
@@ -233,13 +245,47 @@ _prepare_kernel_inputs = jax.jit(population_gather_inputs)
 
 def population_fitness_device(inp: DevicePopulation,
                               genes: jnp.ndarray, *,
-                              method: str = "scan") -> jnp.ndarray:
-    """(B,) makespans of a population — max finish time per candidate."""
+                              method: str = "scan",
+                              sweeps: list | None = None) -> jnp.ndarray:
+    """(B,) makespans of a population — max finish time per candidate.
+    A kernel call appends its sweep count to ``sweeps`` when given; the
+    scan runs no sweeps."""
     if inp.n_subtasks == 0:
         return jnp.zeros(genes.shape[0], jnp.float32)
-    ends = (population_ends_kernel if method == "kernel"
-            else population_ends)(inp, genes)
+    if method == "kernel":
+        ends = population_ends_kernel(inp, genes, sweeps=sweeps)
+    else:
+        ends = population_ends(inp, genes)
     return jnp.max(ends, axis=1)
+
+
+class Fitness(NamedTuple):
+    """A population's makespans and the relaxation work spent on its
+    fitness calls so far, on the device: what the generation step
+    carries. The scan fitness runs no sweeps and counts no calls."""
+
+    fit: jnp.ndarray                # (B,) f32 makespans
+    sweeps: jnp.ndarray             # () int32 — kernel sweeps run
+    calls: jnp.ndarray              # () int32 — kernel fitness calls
+
+
+def score_population(inp: DevicePopulation, genes: jnp.ndarray, *,
+                     method: str = "scan") -> Fitness:
+    """One :func:`population_fitness_device` call as a :class:`Fitness`."""
+    ran: list = []
+    fit = population_fitness_device(inp, genes, method=method, sweeps=ran)
+    sweeps = functools.reduce(jnp.add, ran) if ran \
+        else jnp.zeros((), jnp.int32)
+    return Fitness(fit, sweeps, jnp.asarray(len(ran), jnp.int32))
+
+
+def _count_relax(calls: int, sweeps: int, bound: int) -> None:
+    """The relaxation counters of ``calls`` kernel fitness calls that ran
+    ``sweeps`` sweeps in all, at most ``bound`` each."""
+    if calls:
+        obs.count("relax.calls", calls)
+        obs.count("relax.sweeps", sweeps)
+        obs.count("relax.sweep_bound", calls * bound)
 
 
 # ---------------------------------------------------------------------------
@@ -247,15 +293,17 @@ def population_fitness_device(inp: DevicePopulation,
 # ---------------------------------------------------------------------------
 
 def _generation(inp: DevicePopulation, key: jnp.ndarray,
-                pop: jnp.ndarray, fit: jnp.ndarray, *,
+                pop: jnp.ndarray, scored: Fitness, *,
                 n_cores: int, elite: int, tournament: int,
                 elite_bias: float, p_mut: float, method: str
-                ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(new_pop, new_fit): the full bias-elitist generation as array
+                ) -> tuple[jnp.ndarray, Fitness]:
+    """(new_pop, new_fitness): the full bias-elitist generation as array
     ops. Selection is tournament-of-``k`` by fitness gather; a
     ``elite_bias`` fraction of first parents comes from the sorted
-    elite pool; the top ``elite`` rows survive unchanged."""
+    elite pool; the top ``elite`` rows survive unchanged. The new
+    fitness adds this generation's relaxation work to ``scored``'s."""
     obs.count("ga.step_traces")     # the body runs only while JAX traces
+    fit = scored.fit
     b, t = pop.shape
     order = jnp.argsort(fit)
     pop, fit = pop[order], fit[order]
@@ -275,15 +323,19 @@ def _generation(inp: DevicePopulation, key: jnp.ndarray,
         mut, jax.random.randint(k_g, (b, t), 0, n_cores, pop.dtype), child)
     if elite:
         child = child.at[:elite].set(pop[:elite])
-    return child, population_fitness_device(inp, child, method=method)
+    new = score_population(inp, child, method=method)
+    return child, Fitness(new.fit, scored.sweeps + new.sweeps,
+                          scored.calls + new.calls)
 
 
 def generation_step(params: Any, *, n_tasks: int, n_cores: int,
                     method: str = "scan") -> Callable:
-    """The jitted ``(inp, key, pop, fit) -> (pop, fit)`` generation step
-    :func:`ga_search_device` iterates. Also built on its own by the
-    compiled entry-point manifest (``analysis/entrypoints.py``), the
-    chip smoke test (``chip_smoke.py``) and the TPU compile tests
+    """The jitted ``(inp, key, pop, fitness) -> (pop, fitness)``
+    generation step :func:`ga_search_device` iterates, ``fitness`` a
+    :class:`Fitness` (:func:`score_population` makes the first). Also
+    built on its own by the compiled entry-point manifest
+    (``analysis/entrypoints.py``), the chip smoke test
+    (``chip_smoke.py``) and the TPU compile tests
     (``tests/test_tpu_compile.py``)."""
     p_mut = params.p_mutation if params.p_mutation is not None \
         else max(1.0 / max(n_tasks, 1), 0.02)
@@ -326,23 +378,32 @@ def ga_search_device(graph: AppGraph, machine: MachineModel, *,
                 seeded[i] = np.asarray(e, np.int32)
             pop = jnp.asarray(seeded)
 
-    fitness = functools.partial(population_fitness_device, method=method)
     with obs.span("ga.generations"):
         step = generation_step(par, n_tasks=n_tasks, n_cores=n_cores,
                                method=method)
-        fit = fitness(inp, pop)
+        scored = score_population(inp, pop, method=method)
         obs.count("ga.candidates", par.pop_size)
         for _ in range(par.generations):
             key, kg = jax.random.split(key)
-            pop, fit = step(inp, kg, pop, fit)
+            pop, scored = step(inp, kg, pop, scored)
             obs.count("ga.generations")
             obs.count("ga.candidates", par.pop_size)
-        best = int(jnp.argmin(fit))
-        vec, val = np.asarray(pop[best], np.int32).copy(), float(fit[best])
+        # the best row and the relaxation tallies in one read
+        best = int(jnp.argmin(scored.fit))
+        vec, val, sweeps, calls = jax.device_get(
+            (pop[best], scored.fit[best], scored.sweeps, scored.calls))
+        vec, val = np.asarray(vec, np.int32).copy(), float(val)
+        _count_relax(int(calls), int(sweeps), inp.n_subtasks)
     if par.refine_rounds > 0 and n_tasks > 0 and n_cores > 1:
         key, kr = jax.random.split(key)
+        ran: list = []
+        fitness = functools.partial(population_fitness_device,
+                                    method=method, sweeps=ran)
         vec, val = hill_climb_device(fitness, inp, vec, val, key=kr,
                                      rounds=par.refine_rounds,
                                      moves=par.refine_moves,
                                      n_cores=n_cores)
+        # each round's fitness read has synchronised: the counts are ready
+        _count_relax(len(ran), int(sum(jax.device_get(ran))),
+                     inp.n_subtasks)
     return vec, val

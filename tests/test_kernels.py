@@ -139,3 +139,78 @@ def test_flash_decode_softcap():
     ref = decode_attention_ref(q, kc, vc, pos, softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5,
                                rtol=2e-5)
+
+
+# ---- sim_relax_pop: the sweeps stop at the fixpoint -----------------------
+
+def _fixpoint_sweeps(pred, lat, volbw, duration, release, bound):
+    """Sweeps the float32 oracle runs from zeros until one changes no bit
+    (that sweep counted), at most ``bound``."""
+    from repro.kernels.sim_step import pop_step_np
+    b, s, _ = pred.shape
+    end = np.zeros((b, s + 1), np.float32)
+    for k in range(1, bound + 1):
+        new = pop_step_np(end, pred, lat, volbw, duration, release)
+        if np.array_equal(new.view(np.int32), end[:, :s].view(np.int32)):
+            return k
+        end[:, :s] = new
+    return bound
+
+
+def _random_population():
+    """Eight random mappings of a small app on the 8-core machine, in the
+    kernel's gather form."""
+    from repro.core import SynthParams, dell_poweredge_1950, generate_app
+    from repro.search.device import device_inputs, population_gather_inputs
+    app, m = generate_app(SynthParams(n_tasks=(10, 16)), 2), \
+        dell_poweredge_1950()
+    inp = device_inputs(app, m)
+    genes = np.random.default_rng(3).integers(0, m.n_cores,
+                                              (8, len(app.tasks)),
+                                              dtype=np.int32)
+    return [np.asarray(x) for x in
+            population_gather_inputs(inp, jnp.asarray(genes))]
+
+
+def _chain(b=3, s=20):
+    """One chain of ``s`` subtasks on one core: the dependency edge and
+    the in-order edge both point at the previous subtask, so the last
+    finish time is final only after ``s`` sweeps."""
+    prev = np.arange(-1, s - 1)
+    prev[0] = s                                   # the zero sentinel
+    pred = np.broadcast_to(np.stack([prev, prev], axis=1), (b, s, 2))
+    lag = np.where(pred < s, 0.0, -np.inf).astype(np.float32)
+    duration = np.broadcast_to(np.linspace(1.0, 3.0, s, dtype=np.float32),
+                               (b, s))
+    return [np.ascontiguousarray(pred, np.int32), lag, lag.copy(),
+            np.ascontiguousarray(duration),
+            np.zeros((b, s), np.float32)]
+
+
+@pytest.mark.parametrize("case", ["random", "chain", "below", "zero"])
+def test_sim_relax_pop_stops_at_the_fixpoint(case):
+    """Stopping at the first sweep that changes nothing returns the bits
+    of all ``n_steps`` sweeps, and the count says where it stopped: before
+    the bound on a random population, at the bound on a chain that needs
+    every sweep, at ``n_steps`` when that is below the fixpoint, and no
+    sweep at all for ``n_steps=0``."""
+    from repro.kernels.sim_step import pop_relax_np
+    args = _random_population() if case == "random" else _chain()
+    s = args[0].shape[1]
+    bound = {"random": s, "chain": s, "below": s // 2, "zero": 0}[case]
+    want = pop_relax_np(*args, n_steps=bound)
+    ends, sweeps = ops.sim_relax_pop_sweeps(*args, n_steps=bound)
+    ends, sweeps = np.asarray(ends), int(sweeps)
+    np.testing.assert_array_equal(ends.view(np.int32), want.view(np.int32))
+    np.testing.assert_array_equal(
+        np.asarray(ops.sim_relax_pop(*args, n_steps=bound)).view(np.int32),
+        want.view(np.int32))
+    assert sweeps == _fixpoint_sweeps(*args, bound)
+    if case == "random":
+        assert 0 < sweeps < bound
+    else:
+        assert sweeps == bound
+    if case == "below":
+        assert not np.array_equal(want, pop_relax_np(*args, n_steps=s))
+    if case == "zero":
+        assert not ends.any()

@@ -158,6 +158,32 @@ def test_device_fitness_matches_pop_kernel_oracle_bitforbit(method):
     np.testing.assert_array_equal(fit, ends.max(axis=1))
 
 
+def test_device_ga_kernel_sweeps_counted_and_same_as_scan():
+    """The kernel fitness stops its sweeps at the fixpoint: the search
+    counts one ``relax.calls`` per fitness call (initial, one per
+    generation, one per refine round) with the sweeps run below their S
+    bound, and returns what the scan fitness returns for the same
+    seed. The scan fitness counts no sweeps."""
+    from repro import obs
+    from repro.search.device import ga_search_device
+
+    app, m = _app(1), dell_poweredge_1950()
+    par = GAParams(pop_size=8, generations=3, refine_rounds=2,
+                   refine_moves=6, device=True)
+    obs.reset()
+    vk, fk = ga_search_device(app, m, seed=5, params=par, method="kernel")
+    c = obs.snapshot()["counters"]
+    obs.reset()
+    vs, fs = ga_search_device(app, m, seed=5, params=par, method="scan")
+    assert not any(k.startswith("relax.") for k in obs.snapshot()["counters"])
+    assert np.array_equal(vk, vs) and fk == fs
+    assert c["relax.calls"] == 1 + par.generations + c["ga.refine_rounds"]
+    assert c["relax.sweep_bound"] == \
+        c["relax.calls"] * device_inputs(app, m).n_subtasks
+    assert c["relax.calls"] <= c["relax.sweeps"] < c["relax.sweep_bound"]
+    assert c["ga.step_traces"] == 1
+
+
 def test_device_fitness_matches_host_appendonly_decode():
     """Device fitness == lowering + simulating the host append-only
     decode (``gap_fill=False``) of the same genes — the device decoder's

@@ -100,7 +100,8 @@ def test_generation_step_kernel_compiles(one_chip, monkeypatch):
                             generate_app)
     from repro.kernels import ops
     from repro.search import GAParams
-    from repro.search.device import device_inputs, generation_step
+    from repro.search.device import (Fitness, device_inputs,
+                                     generation_step)
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
 
     machine = cluster_of_multicores(32)
@@ -114,5 +115,7 @@ def test_generation_step_kernel_compiles(one_chip, monkeypatch):
     args = (jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), inp),
             _spec(one_chip, (2,), np.uint32),
             _spec(one_chip, (pop, len(graph.tasks)), jnp.int32),
-            _spec(one_chip, (pop,), jnp.float32))
+            Fitness(_spec(one_chip, (pop,), jnp.float32),
+                    _spec(one_chip, (), jnp.int32),
+                    _spec(one_chip, (), jnp.int32)))
     assert _holds_kernel(step.lower(*args).compile())
