@@ -17,9 +17,9 @@ Two sections, both equivalence-checked while they time:
   against ``simulate_arrays`` (the same loop on the lowered IR), which
   must match **bit for bit** while it times.
 
-``--pallas`` adds a correctness/timing smoke of the ``sim_step``
-kernel path (interpret mode off-TPU, so it is a semantics check, not a
-speed claim). Results append to ``BENCH_sim.json`` so successive PRs
+``--pallas`` adds a correctness/timing smoke of the ``sim_relax_pop``
+kernel path through ``simulate_batch`` (interpret mode off-TPU, so it is
+a semantics check, not a speed claim). Results append to ``BENCH_sim.json`` so successive PRs
 get a perf trajectory; CI runs ``--quick`` and uploads the file with
 the other trajectory artifacts.
 """
@@ -121,7 +121,7 @@ def bench_events(name: str, machine, params: SynthParams, n_apps: int,
 # ---------------------------------------------------------------------------
 def bench_pallas(machine, params: SynthParams, n_apps: int,
                  seed: int) -> dict:
-    """sim_step kernel smoke: batched relaxation through Pallas
+    """sim_relax_pop kernel smoke: ``simulate_batch`` through Pallas
     (interpret mode off-TPU) vs the NumPy CSR path."""
     apps, schedules = _prepare(params, n_apps, seed, machine)
     scenarios = [lower_scenario(g, machine, s)
@@ -135,7 +135,7 @@ def bench_pallas(machine, params: SynthParams, n_apps: int,
            "max_rel_err": float(rel.max())}
     print(f"      pallas apps={n_apps:3d} {1e3 * pallas_s:8.1f} ms "
           f"max_rel_err={row['max_rel_err']:.2e} (float32 vs float64)")
-    assert row["max_rel_err"] < 1e-5, "sim_step kernel diverged"
+    assert row["max_rel_err"] < 1e-5, "sim_relax_pop kernel diverged"
     return row
 
 
@@ -145,7 +145,8 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="CI-sized run")
     ap.add_argument("--json", default="BENCH_sim.json")
     ap.add_argument("--pallas", action="store_true",
-                    help="include the sim_step kernel smoke (slow on CPU)")
+                    help="include the sim_relax_pop kernel smoke "
+                    "(slow on CPU)")
     args = ap.parse_args()
 
     p8 = SynthParams(n_tasks=(15, 25))
@@ -171,7 +172,7 @@ def main() -> None:
 
     pallas = []
     if args.pallas:
-        print("\n== sim_step kernel (interpret off-TPU) ==")
+        print("\n== sim_relax_pop kernel (interpret off-TPU) ==")
         pallas.append(bench_pallas(m8, p8, n_apps=4, seed=0))
 
     out = Path(args.json)

@@ -168,10 +168,11 @@ def _build_generation_step(suite: str) -> Built:
 def _build_sim_relax_pop(suite: str) -> Built:
     import jax
 
-    from ..core.sim_engine import _jitter_durations, _pop_gather_inputs
+    from ..core.lowering import relax_operands
+    from ..core.sim_engine import _jitter_durations
     from ..kernels import ops
     _, _, batch = _scheduled_batch(suite)
-    pred, lat, volbw = _pop_gather_inputs(batch)
+    pred, lat, volbw = relax_operands(batch)
     f32 = functools.partial(np.asarray, dtype=np.float32)
     fn = functools.partial(ops.sim_relax_pop, n_steps=batch.depth)
     base = (pred, f32(lat), f32(volbw), f32(batch.duration),

@@ -192,10 +192,10 @@ def lint_scenario_arrays(sa: lowering.ScenarioArrays) -> None:
 
 
 def lint_batch(batch: lowering.ScenarioBatch) -> None:
-    """The pre-launch check for ``sim_step`` / ``sim_relax`` /
-    ``relax_batch_np``: shapes, sentinel/padding consistency, gather
-    bounds and topological wave indices — everything the relaxation
-    sweep gathers blindly."""
+    """The pre-launch check for the batch relaxations (``sim_relax_pop``
+    through ``simulate_batch``, ``relax_wave_np``, ``relax_batch_np``):
+    shapes, sentinel/padding consistency, gather bounds and topological
+    wave indices — everything the relaxation sweep gathers blindly."""
     b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
     _check_int("n_sub", batch.n_sub)
     check_shape("n_sub", batch.n_sub, (b,))

@@ -22,10 +22,13 @@ gather from:
   placement arrays, per-core schedule-order arrays, and per-subtask
   release floors. This is what the array simulator executes;
 * :class:`ScenarioBatch` — many scenarios padded to one fixed shape
-  ``(B, S, P)`` for the batched relaxation step (``kernels/sim_step.py``
-  is the accelerator form of the same step). Scenarios may mix machines
-  and graphs freely — the lowering already resolved everything to
-  per-edge lags, so core counts never appear in the batch;
+  ``(B, S, P)`` for the batched relaxation step;
+  :func:`relax_operands` appends the in-order core edge as one more
+  column, the ``(B, S, P+1)`` form every relaxation reads (the NumPy
+  sweeps of ``core/sim_engine.py`` and the ``sim_relax_pop`` kernel).
+  Scenarios may mix machines and graphs freely — the lowering already
+  resolved everything to per-edge lags, so core counts never appear in
+  the batch;
 * :class:`PredLayout` — the bounded predecessor layout both padded
   forms (the batch and :class:`PopulationArrays`) share: no row carries
   more than :data:`ROW_COLUMNS` columns, the in-order column included,
@@ -593,8 +596,8 @@ def _scenario_waves(sa: ScenarioArrays, prev: np.ndarray,
 
 def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
     """Pad scenarios (possibly of different graphs AND machines) to one
-    fixed-shape batch for :func:`repro.core.sim_engine.relax_batch_np`
-    / the ``sim_step`` kernel, each graph in its :class:`PredLayout`."""
+    fixed-shape batch for the relaxation (:func:`relax_operands`), each
+    graph in its :class:`PredLayout`."""
     if not scenarios:
         raise ValueError("batch_scenarios needs at least one scenario")
     b = len(scenarios)
@@ -700,6 +703,27 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
         pred_lat=_frozen(pred_lat), pred_volbw=_frozen(pred_volbw),
         wave=_frozen(wave), t_est=_frozen(t_est), depth=depth,
         **fault_fields)
+
+
+def relax_operands(batch: ScenarioBatch
+                   ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """``(pred, lat, volbw)``, each ``(B, S, P+1)``: the batch's edges
+    with the in-order core edge as the last column — ``prev`` as its
+    source with zero lags where ``prev < S``, the sentinel ``S`` with
+    ``-inf`` lags where a row has none. The one place that column is
+    appended; every relaxation of a batch reads these operands
+    (``repro.search.device.population_gather_inputs`` is the
+    device-side twin for a GA population). Cached on the batch."""
+    cached = batch.__dict__.get("_relax_operands")
+    if cached is not None:
+        return cached
+    prev = batch.prev[:, :, None]
+    inorder = np.where(prev < batch.max_subtasks, 0.0, -np.inf)
+    cached = (np.concatenate([batch.pred, prev], axis=2),
+              np.concatenate([batch.pred_lat, inorder], axis=2),
+              np.concatenate([batch.pred_volbw, inorder], axis=2))
+    object.__setattr__(batch, "_relax_operands", cached)
+    return cached
 
 
 def lower_population(graph: AppGraph, machine: MachineModel,
@@ -860,42 +884,3 @@ def population_arrays(graph: AppGraph, machine: MachineModel
     graph._population_arrays = (fp, ma, pa)
     return pa
 
-
-def dense_lags(batch: ScenarioBatch) -> tuple[np.ndarray, np.ndarray]:
-    """(B, S, S) dense latency / vol-over-bw lag tensors for the
-    ``sim_step`` kernel (``-inf`` where no edge): entry ``[b, t, q]`` is
-    the lag of edge ``q -> t``. In-order core edges appear as 0-lag
-    entries; parallel edges between the same pair keep the largest
-    total lag (the only one that can win the readiness max). Fully
-    vectorized scatter (the kernel path must not pay a Python triple
-    loop per call) and cached on the batch."""
-    cached = batch.__dict__.get("_dense_lags")
-    if cached is not None:
-        return cached
-    b, s = batch.n_scenarios, batch.max_subtasks
-    # all edges incl. the zero-lag in-order one, sentinel column q = s
-    src = np.concatenate([batch.pred, batch.prev[:, :, None]], axis=2)
-    e_lat = np.concatenate(
-        [batch.pred_lat,
-         np.where(batch.prev[:, :, None] < s, 0.0, -np.inf)], axis=2)
-    e_volbw = np.concatenate(
-        [batch.pred_volbw,
-         np.where(batch.prev[:, :, None] < s, 0.0, -np.inf)], axis=2)
-    # flat (b, t, q) slot per edge, width s+1 so the sentinel lands in a
-    # dropped column; keep only the max-total-lag edge per slot
-    slot = ((np.arange(b)[:, None, None] * s
-             + np.arange(s)[None, :, None]) * (s + 1) + src).reshape(-1)
-    total = (e_lat + e_volbw).reshape(-1)
-    real = np.isfinite(total)
-    slot, total = slot[real], total[real]
-    best = np.full(b * s * (s + 1), -np.inf)
-    np.maximum.at(best, slot, total)
-    win = total == best[slot]
-    lat_flat = np.full(b * s * (s + 1), -np.inf)
-    volbw_flat = np.full(b * s * (s + 1), -np.inf)
-    lat_flat[slot[win]] = e_lat.reshape(-1)[real][win]
-    volbw_flat[slot[win]] = e_volbw.reshape(-1)[real][win]
-    lat = lat_flat.reshape(b, s, s + 1)[:, :, :s]
-    volbw = volbw_flat.reshape(b, s, s + 1)[:, :, :s]
-    object.__setattr__(batch, "_dense_lags", (lat, volbw))
-    return lat, volbw

@@ -24,8 +24,10 @@ Two execution paths, both fed by :mod:`repro.core.lowering`:
   time-coupled process and stays on the per-scenario event path; the
   batched path is the throughput validator (`benchmarks/sim_bench.py`).
   ``backend="pallas"`` runs the same sweep as the sparse population
-  kernel (``kernels/sim_step.sim_relax_pop``) on padded (B, S, P+1)
-  predecessor gathers — O(B·S·P) memory, so 1k+-subtask suites fit.
+  kernel (``kernels/sim_step.sim_relax_pop``). Every path reads the
+  same padded (B, S, P+1) predecessor gathers,
+  :func:`~repro.core.lowering.relax_operands` — O(B·S·P) memory, so
+  1k+-subtask suites fit.
 """
 
 from __future__ import annotations
@@ -37,7 +39,7 @@ import numpy as np
 
 from .. import obs
 from .lowering import (ScenarioArrays, ScenarioBatch, batch_scenarios,
-                       lower_scenario)
+                       lower_scenario, relax_operands)
 from .machine import MachineModel
 from .mpaha import AppGraph
 from .simulator import SimResult
@@ -282,38 +284,23 @@ def simulate_scenario(graph: AppGraph, machine: MachineModel, schedule,
 # batched fixed-shape relaxation (whole suites in one call)
 # ---------------------------------------------------------------------------
 
-def _gather_inputs(batch: ScenarioBatch) -> tuple[np.ndarray, np.ndarray]:
-    """(B, S, P+1) gather sources and lags shared by both relaxation
-    paths — the in-order core edge rides as one more predecessor column
-    with zero lag, indices are flattened against the ``(B, S+1)`` end
-    buffer, and the per-edge lag is the prefolded ``lat + vol/bw`` (one
-    add per sweep; within 1 ulp of the event simulator's two-add
-    expression). One construction keeps ``relax_batch_np`` and
-    ``relax_wave_np`` structurally identical; cached on the batch."""
-    cached = batch.__dict__.get("_gather_inputs")
-    if cached is not None:
-        return cached
-    b, s = batch.n_scenarios, batch.max_subtasks
-    idx = np.concatenate([batch.pred, batch.prev[:, :, None]], axis=2)
-    idx = idx + (np.arange(b) * (s + 1))[:, None, None]
-    lag = np.concatenate(
-        [batch.pred_lat + batch.pred_volbw,
-         np.where(batch.prev[:, :, None] < s, 0.0, -np.inf)], axis=2)
-    object.__setattr__(batch, "_gather_inputs", (idx, lag))
-    return idx, lag
-
-
 def relax_batch_np(batch: ScenarioBatch, duration: np.ndarray | None = None,
                    n_steps: int | None = None) -> np.ndarray:
     """NumPy relaxation over the padded CSR batch: ``(B, S)`` finish
     times after ``n_steps`` synchronous sweeps (default: the batch's
     fixpoint depth). ``duration`` overrides ``batch.duration`` (the
     jitter hook). The sweep is allocation-free: gathers run through one
-    flat ``np.take`` into a preallocated buffer."""
+    flat ``np.take`` into a preallocated buffer. It reads
+    :func:`~repro.core.lowering.relax_operands` with the sources
+    flattened against the ``(B, S+1)`` end buffer and the lags prefolded
+    to ``lat + vol/bw`` (one add per sweep; within 1 ulp of the event
+    simulator's two-add expression)."""
     b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
     dur = batch.duration if duration is None else duration
     steps = batch.depth if n_steps is None else n_steps
-    idx, lag = _gather_inputs(batch)
+    pred, lat, volbw = relax_operands(batch)
+    idx = pred + (np.arange(b) * (s + 1))[:, None, None]
+    lag = lat + volbw
     end = np.zeros((b, s + 1))                 # slot s = sentinel (always 0)
     flat = end.reshape(-1)
     gath = np.empty((b, s, p + 1))
@@ -331,16 +318,16 @@ def relax_batch_np(batch: ScenarioBatch, duration: np.ndarray | None = None,
 def _wave_plan(batch: ScenarioBatch):
     """Wave-ordered evaluation plan, cached on the batch: every live
     (scenario, row) pair — subtasks and join rows — sorted by
-    topological level, with its
-    gather sources (preds + in-order edge) resolved to flat indices
-    into the ``(B, S+1)`` end buffer and its lags prefolded. Segment
-    ``w`` of the plan depends only on segments ``< w``, so one pass
-    computes every finish time exactly once."""
+    topological level, with its gather sources
+    (:func:`~repro.core.lowering.relax_operands`: preds + in-order
+    edge) resolved to flat indices into the ``(B, S+1)`` end buffer and
+    its lags prefolded as in :func:`relax_batch_np`. Segment ``w`` of
+    the plan depends only on segments ``< w``, so one pass computes
+    every finish time exactly once."""
     plan = batch.__dict__.get("_wave_plan")
     if plan is not None:
         return plan
     b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
-    idx, lag = _gather_inputs(batch)
     flat_pos = np.arange(b * s)
     live = (flat_pos % s) < batch.n_rows.astype(np.int64)[flat_pos // s]
     order = flat_pos[live]
@@ -350,13 +337,15 @@ def _wave_plan(batch: ScenarioBatch):
     # segment boundaries: one slice per wave value
     bounds = np.searchsorted(waves, np.arange(1, waves[-1] + 1 if len(waves)
                                               else 1))
+    pred, lat, volbw = (x.reshape(b * s, p + 1)[order]
+                        for x in relax_operands(batch))
+    base = (order // s) * (s + 1)           # the row's (S+1)-slot block
     plan = (order,
             np.concatenate([[0], bounds, [len(order)]]).astype(np.int64),
-            idx.reshape(b * s, p + 1)[order],
-            lag.reshape(b * s, p + 1)[order],
+            pred + base[:, None], lat + volbw,
             batch.release.reshape(-1)[order],
             # scatter target in the (B, S+1) end buffer
-            (order // s) * (s + 1) + (order % s))
+            base + (order % s))
     object.__setattr__(batch, "_wave_plan", plan)
     return plan
 
@@ -411,17 +400,11 @@ def relax_wave_faults(batch: ScenarioBatch,
     dur = dur[order]
     p1 = idx.shape[1]                           # P + 1 gather columns
     k2 = batch.deg_t.shape[3]
-    # split lags back out of the prefolded form: the degrade factor
+    # the split lags, not the plan's prefolded ones: the degrade factor
     # multiplies latency and vol/bw separately (like the event loops);
     # the in-order core edge (last column) is comm-free -> neutral pad
-    e_lat = np.concatenate(
-        [batch.pred_lat,
-         np.where(batch.prev[:, :, None] < s, 0.0, -np.inf)],
-        axis=2).reshape(b * s, p1)[order]
-    e_volbw = np.concatenate(
-        [batch.pred_volbw,
-         np.where(batch.prev[:, :, None] < s, 0.0, -np.inf)],
-        axis=2).reshape(b * s, p1)[order]
+    _, e_lat, e_volbw = (x.reshape(b * s, p1)[order]
+                         for x in relax_operands(batch))
     deg_t = np.concatenate(
         [batch.deg_t, np.full((b, s, 1, k2), np.inf)],
         axis=2).reshape(b * s, p1, k2)[order]
@@ -542,31 +525,10 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
     return result
 
 
-def _pop_gather_inputs(batch: ScenarioBatch):
-    """(B, S, P+1) gather sources + split lat/volbw lags for the sparse
-    population kernel (``kernels/sim_step.sim_relax_pop``): the in-order
-    core edge rides as one more zero-lag predecessor column, pads keep
-    the sentinel index ``S`` with ``-inf`` lags. Cached on the batch —
-    unlike :func:`~repro.core.lowering.dense_lags` this stays O(B·S·P),
-    so 1k+-subtask batches fit on device."""
-    cached = batch.__dict__.get("_pop_gather_inputs")
-    if cached is not None:
-        return cached
-    s = batch.max_subtasks
-    prev = batch.prev[:, :, None]
-    pred = np.concatenate([batch.pred, prev], axis=2)
-    inorder = np.where(prev < s, 0.0, -np.inf)
-    lat = np.concatenate([batch.pred_lat, inorder], axis=2)
-    volbw = np.concatenate([batch.pred_volbw, inorder], axis=2)
-    cached = (pred, lat, volbw)
-    object.__setattr__(batch, "_pop_gather_inputs", cached)
-    return cached
-
-
 def _relax_pallas(batch: ScenarioBatch, duration: np.ndarray) -> np.ndarray:
     from ..kernels.ops import sim_relax_pop
     with obs.span("suite.gather"):
-        pred, lat, volbw = _pop_gather_inputs(batch)
+        pred, lat, volbw = relax_operands(batch)
     with obs.span("suite.relax"):
         end = sim_relax_pop(pred, lat, volbw, duration, batch.release,
                             n_steps=batch.depth)

@@ -5,11 +5,10 @@ anywhere else. Interpret mode exists for the CPU tests only: no chip run
 may rely on it, and ``chip_smoke.py`` fails when a kernel's compiled
 program holds no ``tpu_custom_call``.
 
-The scheduling kernels (``sched_score`` / ``sim_step`` / ``sim_relax`` /
-``sim_relax_pop`` / ``sim_relax_pop_sweeps``) gather through
-caller-provided index arrays; an out-of-bounds index does not crash on
-device, it clamps and reads the wrong slot, returning a plausible wrong
-score. Their wrappers therefore run the tracer-safe checks from
+The scheduling kernels (``sched_score`` / ``sim_relax_pop`` /
+``sim_relax_pop_sweeps``) gather through caller-provided index arrays;
+an out-of-bounds index does not crash on device, it clamps and reads
+the wrong slot, returning a plausible wrong score. Their wrappers therefore run the tracer-safe checks from
 :mod:`repro.analysis.ir_lint` before launch: shapes always (static
 metadata even under ``jax.jit`` tracing — the device GA calls
 ``sim_relax_pop_sweeps`` inside its jitted generation step),
@@ -68,26 +67,6 @@ def sched_score(drain, frontiers, release, *, apps_block=128,
     return _ss.sched_score(drain, frontiers, release,
                            apps_block=apps_block, cores_block=cores_block,
                            interpret=not _on_tpu())
-
-
-def sim_step(end, lat, volbw, duration, release, *, sub_block=128):
-    b, s = end.shape
-    check_shape("sim_step.lat", lat, (b, s, s))
-    check_shape("sim_step.volbw", volbw, (b, s, s))
-    check_shape("sim_step.duration", duration, (b, s))
-    check_shape("sim_step.release", release, (b, s))
-    return _sim.sim_step(end, lat, volbw, duration, release,
-                         sub_block=sub_block, interpret=not _on_tpu())
-
-
-def sim_relax(lat, volbw, duration, release, *, n_steps, sub_block=128):
-    b, s, _ = lat.shape
-    check_shape("sim_relax.lat", lat, (b, s, s))
-    check_shape("sim_relax.volbw", volbw, (b, s, s))
-    check_shape("sim_relax.duration", duration, (b, s))
-    check_shape("sim_relax.release", release, (b, s))
-    return _sim.sim_relax(lat, volbw, duration, release, n_steps=n_steps,
-                          sub_block=sub_block, interpret=not _on_tpu())
 
 
 def _check_relax_pop(pred, lat, volbw, duration, release, name):

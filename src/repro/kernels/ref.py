@@ -36,7 +36,6 @@ ssd_sequential_ref = ssd_sequential
 from .sched_ref import sched_score_np as sched_score_ref  # noqa: E402
 from .sim_step import pop_relax_np as sim_relax_pop_ref  # noqa: E402
 from .sim_step import pop_step_np as sim_pop_step_ref  # noqa: E402
-from .sim_step import sim_step_np as sim_step_ref  # noqa: E402
 
 
 def decode_attention_ref(q, k_cache, v_cache, pos, *, scale=None,

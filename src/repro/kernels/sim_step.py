@@ -1,31 +1,29 @@
 """Batched simulation-relaxation Pallas kernel.
 
-One synchronous sweep of the analytic execution recurrence over a whole
-suite of lowered scenarios (``repro.core.lowering.dense_lags`` builds
-the inputs):
+One synchronous sweep of the analytic execution recurrence over a batch
+of lowered scenarios or a GA population, in the padded predecessor
+gather form (``repro.core.lowering.relax_operands`` builds it for a
+batch, ``repro.search.device.population_gather_inputs`` for a
+population):
 
     end'[b, s] = duration[b, s]
                + max(release[b, s], 0,
-                     max_j (end[b, j] + lat[b, s, j]) + volbw[b, s, j])
+                     max_j (end[b, pred[b, s, j]] + lat[b, s, j])
+                     + volbw[b, s, j])
 
-``lat``/``volbw`` are dense ``(B, S, S)`` lag tensors, ``-inf`` where
-subtask ``j`` does not gate subtask ``s`` (dependency edges carry the
-comm latency and ``vol / bw``; the in-order core edge carries 0; the 0
-floor stands in for an idle core). The two-add shape ``(end + lat) +
-volbw`` matches the event simulator's ``now + latency + vol/bandwidth``
-expression, so the float paths agree term by term.
+``pred``/``lat``/``volbw`` are ``(B, S, P+1)``: dependency edges carry
+the comm latency and ``vol / bw``, the last column is the in-order core
+edge with 0 lags, pads point at the always-zero sentinel slot ``S``
+with ``-inf`` lags (the 0 floor stands in for an idle core). The
+two-add shape ``(end + lat) + volbw`` matches the event simulator's
+``now + latency + vol/bandwidth`` expression, so the float paths agree
+term by term.
 
-The max-plus form is deliberately kernel-friendly: per grid cell one
-VMEM tile of each lag tensor, a broadcast row of the current ends, an
-elementwise add-add-max reduction along the lane axis — no gathers, no
-cross-tile reductions. ``sim_relax`` iterates the step to the batch's
-fixpoint depth under one ``jit``; the population variant
-``sim_relax_pop`` stops its sweeps at the fixpoint itself, with
-``n_steps`` as the bound. The NumPy oracle ``sim_step_np`` is
-the allclose target (re-exported as ``kernels.ref.sim_step_ref``); the
+``sim_relax_pop`` iterates the sweep from zeros and stops at the
+fixpoint, with ``n_steps`` as the bound. The NumPy oracles
+``pop_step_np`` / ``pop_relax_np`` are its bit-for-bit targets; the
 float64 production path on CPU is the padded-CSR relaxation in
-``repro.core.sim_engine.relax_batch_np`` — tests sweep all three
-against each other.
+``repro.core.sim_engine`` — tests sweep them against each other.
 """
 
 from __future__ import annotations
@@ -39,26 +37,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def sim_step_np(end, lat, volbw, duration, release) -> np.ndarray:
-    """NumPy oracle for one dense relaxation sweep (dtype-preserving).
-
-    ``end`` (B, S); ``lat``/``volbw`` (B, S, S) with ``-inf`` non-edges;
-    ``duration``/``release`` (B, S)."""
-    end = np.asarray(end)
-    ready = ((end[:, None, :] + np.asarray(lat))
-             + np.asarray(volbw)).max(axis=-1, initial=-np.inf)
-    zero = end.dtype.type(0.0)
-    return np.asarray(duration) + np.maximum(np.asarray(release),
-                                             np.maximum(ready, zero))
-
-
-def _step_kernel(end_ref, lat_ref, volbw_ref, dur_ref, rel_ref, o_ref):
-    end = end_ref[...]                        # (1, 1, S)
-    ready = jnp.max((end + lat_ref[...]) + volbw_ref[...], axis=-1)
-    o_ref[...] = dur_ref[...] + jnp.maximum(rel_ref[...],
-                                            jnp.maximum(ready, 0.0))
-
-
 def _pad_axis(x, axis: int, pad: int, value: float):
     if pad == 0:
         return x
@@ -67,76 +45,6 @@ def _pad_axis(x, axis: int, pad: int, value: float):
     return jnp.pad(x, widths, constant_values=value)
 
 
-def _prepare(lat, volbw, duration, release, sub_block: int,
-             interpret: bool):
-    """Shared cast/pad/pallas_call setup: returns a one-sweep step
-    callable over padded ``(B, Sp)`` ends plus the (batch, valid,
-    padded) sizes — sim_step and sim_relax must never drift apart."""
-    lat = jnp.asarray(lat, jnp.float32)
-    volbw = jnp.asarray(volbw, jnp.float32)
-    duration = jnp.asarray(duration, jnp.float32)
-    release = jnp.asarray(release, jnp.float32)
-    b, s, _ = lat.shape
-    sp = max(sub_block, ((s + 127) // 128) * 128)
-    sb = min(sub_block, sp)
-    pad = sp - s
-    lat = _pad_axis(_pad_axis(lat, 1, pad, -jnp.inf), 2, pad, -jnp.inf)
-    volbw = _pad_axis(_pad_axis(volbw, 1, pad, -jnp.inf), 2, pad, -jnp.inf)
-    duration = _pad_axis(duration, 1, pad, 0.0)
-    release = _pad_axis(release, 1, pad, 0.0)
-
-    call = pl.pallas_call(
-        _step_kernel,
-        grid=(b, sp // sb),
-        in_specs=[pl.BlockSpec((1, 1, sp), lambda i, j: (i, 0, 0)),
-                  pl.BlockSpec((1, sb, sp), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, sb, sp), lambda i, j: (i, j, 0)),
-                  pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, sb), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, sb), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((b, sp), jnp.float32),
-        interpret=interpret,
-    )
-
-    def step(end):
-        return call(end[:, None, :], lat, volbw, duration, release)
-
-    return step, b, s, sp
-
-
-@functools.partial(jax.jit, static_argnames=("n_steps", "sub_block",
-                                             "interpret"))
-def sim_relax(lat, volbw, duration, release, *, n_steps: int,
-              sub_block: int = 128, interpret: bool = False):
-    """Iterate the relaxation step ``n_steps`` times from all-zero ends.
-
-    ``n_steps`` is the longest path of the scenario dependency graphs
-    (``ScenarioBatch.depth``) — after that many sweeps every finish
-    time is final. Returns (B, S) float32 ends.
-    """
-    step, b, s, sp = _prepare(lat, volbw, duration, release, sub_block,
-                              interpret)
-    end = jax.lax.fori_loop(0, n_steps, lambda _, e: step(e),
-                            jnp.zeros((b, sp), jnp.float32))
-    return end[:, :s]
-
-
-@functools.partial(jax.jit, static_argnames=("sub_block", "interpret"))
-def sim_step(end, lat, volbw, duration, release, *, sub_block: int = 128,
-             interpret: bool = False):
-    """One relaxation sweep (the oracle-shaped entry point)."""
-    step, _, s, sp = _prepare(lat, volbw, duration, release, sub_block,
-                              interpret)
-    end = _pad_axis(jnp.asarray(end, jnp.float32), 1, sp - s, 0.0)
-    return step(end)[:, :s]
-
-
-# ---------------------------------------------------------------------------
-# population-axis variant: sparse predecessor gathers instead of dense
-# (B, S, S) lag tensors — O(B·S·P) memory, the shape a device-resident
-# GA population (repro.search.device) and large ScenarioBatches need.
-# ---------------------------------------------------------------------------
-
 def pop_step_np(end, pred, lat, volbw, duration, release) -> np.ndarray:
     """NumPy oracle for one sparse population sweep (dtype-preserving).
 
@@ -144,7 +52,7 @@ def pop_step_np(end, pred, lat, volbw, duration, release) -> np.ndarray:
     ``pred`` (B, S, P) int gather sources into the E axis (pads point at
     a sentinel slot); ``lat``/``volbw`` (B, S, P) with ``-inf`` pads;
     ``duration``/``release`` (B, S). The two-add shape ``(end + lat) +
-    volbw`` matches the dense kernel and the event simulator."""
+    volbw`` matches the kernel and the event simulator."""
     end = np.asarray(end)
     b = end.shape[0]
     g = end[np.arange(b)[:, None, None], np.asarray(pred)]

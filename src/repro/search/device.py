@@ -198,7 +198,9 @@ def population_gather_inputs(
                    jnp.ndarray, jnp.ndarray]:
     """(pred, lat, volbw, duration, release) in the population-kernel
     gather shape — the device decode resolved to ``sim_relax_pop``
-    inputs, the in-order core edge appended as a zero-lag column."""
+    inputs, the in-order core edge appended as a zero-lag column. The
+    device-side twin of :func:`repro.core.lowering.relax_operands` (the
+    same layout and sentinel rule, on traced arrays)."""
     s = inp.n_subtasks
     b = genes.shape[0]
     core, dur, lag_lat, lag_volbw = _decode_common(inp, genes)

@@ -19,8 +19,8 @@ using a Bias-Elitist Genetic Algorithm"). The scheme here:
   (:func:`~repro.core.lowering.lower_population`) and run the
   wave-scheduled :func:`~repro.core.sim_engine.simulate_batch` — the
   analytic as-executed makespan of every candidate in one call
-  (``backend="pallas"`` routes the same sweep through the ``sim_step``
-  kernel);
+  (``GAParams(device=True)`` scores on the device instead,
+  ``search/device.py``);
 * selection is tournament with an elite bias (a configurable fraction
   of parent draws come from the elite pool), recombination is uniform
   crossover, mutation resamples each gene with probability
@@ -64,7 +64,6 @@ class GAParams:
     p_mutation: float | None = None  # per-gene; default max(1/n_tasks, .02)
     refine_rounds: int = 3          # hill-climbing rounds on the winner
     refine_moves: int = 48          # sampled single-task moves per round
-    backend: str = "numpy"          # fitness path: "numpy" | "pallas"
     device: bool = False            # device-resident loop (search/device)
 
     def __post_init__(self) -> None:
@@ -88,15 +87,11 @@ class GAParams:
         if self.refine_rounds < 0 or self.refine_moves < 0:
             raise ValueError("refine_rounds/refine_moves must be >= 0, got "
                              f"{self.refine_rounds}/{self.refine_moves}")
-        if self.backend not in ("numpy", "pallas"):
-            raise ValueError(f"unknown fitness backend {self.backend!r} "
-                             "(expected 'numpy' or 'pallas')")
 
 
 def population_fitness(graph: AppGraph, machine: MachineModel, population,
                        *, releases: dict[int, float] | None = None,
-                       frozen: dict | None = None,
-                       backend: str = "numpy") -> np.ndarray:
+                       frozen: dict | None = None) -> np.ndarray:
     """(B,) as-executed makespan per chromosome — decode all, lower to
     one batch, simulate once. The GA's only objective call. ``frozen``
     pins immutable history into every decoded candidate (mid-flight
@@ -105,7 +100,7 @@ def population_fitness(graph: AppGraph, machine: MachineModel, population,
                                   releases=releases, frozen=frozen)
     batch = lowering.lower_population(graph, machine, schedules,
                                       releases=releases)
-    return simulate_batch(batch, backend=backend).t_exec
+    return simulate_batch(batch).t_exec
 
 
 def _mutate(population: np.ndarray, rng: np.random.Generator,
@@ -186,7 +181,7 @@ def ga_search(graph: AppGraph, machine: MachineModel, *, seed: int = 0,
 
     def evaluate(p):
         return population_fitness(graph, machine, p, releases=releases,
-                                  frozen=frozen, backend=par.backend)
+                                  frozen=frozen)
 
     fit = evaluate(pop)
     for _ in range(par.generations):
@@ -200,8 +195,7 @@ def ga_search(graph: AppGraph, machine: MachineModel, *, seed: int = 0,
         vec, val = hill_climb(graph, machine, vec, val, rng=rng,
                               rounds=par.refine_rounds,
                               moves=par.refine_moves,
-                              releases=releases, frozen=frozen,
-                              backend=par.backend)
+                              releases=releases, frozen=frozen)
     return vec, val
 
 

@@ -48,8 +48,7 @@ def hill_climb(graph: AppGraph, machine: MachineModel, vec: np.ndarray,
                fit: float, *, rng: np.random.Generator, rounds: int = 3,
                moves: int = 48,
                releases: dict[int, float] | None = None,
-               frozen: dict | None = None,
-               backend: str = "numpy") -> tuple[np.ndarray, float]:
+               frozen: dict | None = None) -> tuple[np.ndarray, float]:
     """Refine ``vec`` (current fitness ``fit``); returns the improved
     ``(vector, fitness)``. Deterministic given ``rng``'s state.
     ``frozen`` pins immutable history into every candidate."""
@@ -62,7 +61,7 @@ def hill_climb(graph: AppGraph, machine: MachineModel, vec: np.ndarray,
                                       releases=releases, frozen=frozen)
         batch = lowering.lower_population(graph, machine, schedules,
                                           releases=releases)
-        f = simulate_batch(batch, backend=backend).t_exec
+        f = simulate_batch(batch).t_exec
         best = int(np.argmin(f))
         if f[best] >= fit - 1e-12:
             break

@@ -282,7 +282,7 @@ def test_device_ga_respects_release_floors():
 @pytest.mark.parametrize("bad", [
     dict(pop_size=0), dict(elite=13), dict(elite=-1), dict(generations=0),
     dict(tournament=0), dict(elite_bias=1.5), dict(elite_bias=-0.1),
-    dict(p_mutation=2.0), dict(refine_rounds=-1), dict(backend="torch"),
+    dict(p_mutation=2.0), dict(refine_rounds=-1), dict(refine_moves=-1),
 ])
 def test_gaparams_validated_on_construction(bad):
     with pytest.raises(ValueError):

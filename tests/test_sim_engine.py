@@ -1,8 +1,8 @@
 """Simulator equivalence: the lowered event loop must reproduce the
 seed ``simulate()`` bit-for-bit (contention + jitter + releases), and
 the batched relaxation must reproduce the analytic event semantics —
-single vs batched, NumPy CSR vs wave-scheduled vs dense Pallas kernel
-are all swept against each other."""
+single vs batched, NumPy CSR vs wave-scheduled vs the sparse Pallas
+kernel are all swept against each other."""
 
 import numpy as np
 import pytest
@@ -13,6 +13,7 @@ from repro.core import (AppGraph, Schedule, SimResult, SynthParams,
                         hp_bl260c, lower_scenario, paper_suite_8core,
                         repeat_batch, simulate, simulate_arrays,
                         simulate_batch, simulate_scenario, simulate_suite)
+from repro.core.lowering import relax_operands
 from repro.core.machine import CommLevel, MachineModel
 from repro.core.sim_engine import relax_batch_np, relax_wave_np
 from repro.online import ArrivalParams, generate_workload, replay_fifo
@@ -180,49 +181,44 @@ def test_repeat_batch_tiles_scenarios():
     np.testing.assert_array_equal(res.t_exec[:2], res.t_exec[4:])
 
 
-# ---------------------------------------------------------------------------
-# sim_step Pallas kernel vs oracles
-# ---------------------------------------------------------------------------
-
-def test_sim_step_kernel_matches_numpy_oracle():
-    from repro.kernels.sim_step import sim_step, sim_step_np
-    rng = np.random.default_rng(0)
-    b, s = 3, 37
-    lat = np.where(rng.uniform(size=(b, s, s)) < 0.2,
-                   rng.uniform(0.0, 1e-4, (b, s, s)), -np.inf)
-    volbw = np.where(lat > -np.inf, rng.uniform(0.0, 2.0, (b, s, s)),
-                     -np.inf)
-    end = rng.uniform(0.0, 50.0, (b, s))
-    dur = rng.uniform(0.1, 5.0, (b, s))
-    rel = rng.uniform(0.0, 20.0, (b, s))
-    got = np.asarray(sim_step(end, lat, volbw, dur, rel, interpret=True))
-    want = sim_step_np(end.astype(np.float32), lat.astype(np.float32),
-                       volbw.astype(np.float32), dur.astype(np.float32),
-                       rel.astype(np.float32))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-4)
-
-
-def test_sim_relax_kernel_matches_csr_relaxation():
-    from repro.core.lowering import dense_lags
-    from repro.kernels.sim_step import sim_relax
+def test_relax_operands_append_the_in_order_column():
     m = dell_poweredge_1950()
-    apps, schedules = _scenarios(m, SynthParams(n_tasks=(5, 10)), 3)
+    apps, schedules = _scenarios(m, SynthParams(n_tasks=(10, 15)), 4)
     batch = batch_scenarios([lower_scenario(g, m, s)
                              for g, s in zip(apps, schedules)])
-    ref = relax_wave_np(batch)
-    lat, volbw = dense_lags(batch)
-    got = np.asarray(sim_relax(lat, volbw, batch.duration, batch.release,
-                               n_steps=batch.depth, interpret=True))
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-3)
+    b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
+    pred, lat, volbw = relax_operands(batch)
+    assert relax_operands(batch)[0] is pred          # cached on the batch
+    for x in (pred, lat, volbw):
+        assert x.shape == (b, s, p + 1)
+    assert pred.dtype == np.int64
+    assert lat.dtype == volbw.dtype == np.float64
+    np.testing.assert_array_equal(pred[:, :, :p], batch.pred)
+    np.testing.assert_array_equal(lat[:, :, :p], batch.pred_lat)
+    np.testing.assert_array_equal(volbw[:, :, :p], batch.pred_volbw)
+    np.testing.assert_array_equal(pred[:, :, p], batch.prev)
+    has_prev = batch.prev < s
+    assert has_prev.any() and not has_prev.all()
+    for x in (lat, volbw):
+        assert np.all(x[:, :, p][has_prev] == 0.0)
+        assert np.all(x[:, :, p][~has_prev] == -np.inf)
+    assert np.array_equal(relax_wave_np(batch), relax_batch_np(batch))
 
 
-def test_simulate_batch_pallas_backend_smoke():
+# ---------------------------------------------------------------------------
+# sim_relax_pop Pallas kernel vs the CSR wave
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n_apps,n_tasks", [(2, (3, 5)), (3, (5, 10))])
+def test_simulate_batch_pallas_backend_smoke(n_apps, n_tasks):
     m = dell_poweredge_1950()
-    apps, schedules = _scenarios(m, SynthParams(n_tasks=(3, 5)), 2)
+    apps, schedules = _scenarios(m, SynthParams(n_tasks=n_tasks), n_apps)
     scens = [lower_scenario(g, m, s) for g, s in zip(apps, schedules)]
     ref = simulate_batch(scens, backend="numpy")
     got = simulate_batch(scens, backend="pallas")
     np.testing.assert_allclose(got.t_exec, ref.t_exec, rtol=1e-5, atol=1e-3)
+    np.testing.assert_allclose(got.subtask_end, ref.subtask_end, rtol=1e-5,
+                               atol=1e-3)
     with pytest.raises(ValueError):
         simulate_batch(scens, backend="cuda")
 

@@ -134,8 +134,7 @@ def test_manifest_sched_entries_clean():
 #: the full public op list of kernels/ops.py — a new wrapper must be
 #: added here AND call check_shape/check_gather_bounds before launch
 OPS = {"flash_attention", "rmsnorm", "ssd_scan", "sched_score",
-       "sim_step", "sim_relax", "sim_relax_pop", "sim_relax_pop_sweeps",
-       "flash_decode"}
+       "sim_relax_pop", "sim_relax_pop_sweeps", "flash_decode"}
 
 
 def test_every_op_wrapper_checked():
