@@ -26,6 +26,9 @@ named. Checks are pure reads — nothing is mutated, nothing is lowered.
 
 from __future__ import annotations
 
+import functools
+import sys
+
 import numpy as np
 
 from ..core import lowering
@@ -63,16 +66,39 @@ def _concrete(arr):
         return None
 
 
+def _device_span(idx):
+    """``(min, max)`` of a concrete jax array, reduced where it lives
+    (reading it to the host would copy every index to learn two), or
+    ``None`` for anything else: host arrays, sequences, tracers, empty
+    arrays."""
+    jax = sys.modules.get("jax")
+    if jax is None or not isinstance(idx, jax.Array) \
+            or isinstance(idx, jax.core.Tracer) or idx.size == 0:
+        return None
+    return np.asarray(_min_max()(idx))
+
+
+@functools.cache
+def _min_max():
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda a: jnp.stack([a.min(), a.max()]))
+
+
 def check_gather_bounds(idx, hi: int, name: str) -> None:
     """Every index in ``[0, hi]`` (``hi`` itself is the padding
     sentinel slot). Silent out-of-bounds gathers are exactly the
     device failure mode this module exists to catch — XLA clamps, the
     kernel reads the wrong subtask's end, and the score comes back
-    plausible but wrong. No-ops on tracers."""
-    a = _concrete(idx)
-    if a is None or a.size == 0:
-        return
-    lo, top = int(a.min()), int(a.max())
+    plausible but wrong. A device array is checked on the device.
+    No-ops on tracers."""
+    span = _device_span(idx)
+    if span is None:
+        a = _concrete(idx)
+        if a is None or a.size == 0:
+            return
+        span = a.min(), a.max()
+    lo, top = (int(v) for v in span)
     if lo < 0 or top > hi:
         _fail(name, f"gather-bounds: indices span [{lo}, {top}], "
                     f"outside [0, {hi}]")

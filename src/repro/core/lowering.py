@@ -28,7 +28,10 @@ gather from:
   sweeps of ``core/sim_engine.py`` and the ``sim_relax_pop`` kernel).
   Scenarios may mix machines and graphs freely — the lowering already
   resolved everything to per-edge lags, so core counts never appear in
-  the batch;
+  the batch. A batch of repeated scenarios (a jitter sweep) keeps its
+  ``P``-wide edge arrays at one row per distinct scenario and maps the
+  ``B`` scenarios onto them (``rows``); :func:`expand_rows` gives the
+  ``B``-row view;
 * :class:`PredLayout` — the bounded predecessor layout both padded
   forms (the batch and :class:`PopulationArrays`) share: no row carries
   more than :data:`ROW_COLUMNS` columns, the in-order column included,
@@ -42,7 +45,7 @@ All arrays are frozen (``writeable=False``): consumers share them.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Any
 
 import numpy as np
@@ -410,9 +413,9 @@ class ScenarioBatch:
     duration: np.ndarray            # (B, S)    f64 — exec on assigned core
     release: np.ndarray             # (B, S)    f64
     prev: np.ndarray                # (B, S)    int64 — in-order edge, S = none
-    pred: np.ndarray                # (B, S, P) int64 — dependency, S = pad
-    pred_lat: np.ndarray            # (B, S, P) f64 — comm latency, -inf pad
-    pred_volbw: np.ndarray          # (B, S, P) f64 — vol / bw, -inf pad
+    pred: np.ndarray                # (D, S, P) int64 — dependency, S = pad
+    pred_lat: np.ndarray            # (D, S, P) f64 — comm latency, -inf pad
+    pred_volbw: np.ndarray          # (D, S, P) f64 — vol / bw, -inf pad
     wave: np.ndarray                # (B, S)    int32 — topological level
     t_est: np.ndarray               # (B,)      f64 — per-scenario makespan
     depth: int                      # relaxation steps to reach fixpoint
@@ -421,8 +424,11 @@ class ScenarioBatch:
     fail_t: np.ndarray | None = None        # (B, S) assigned core's fail, inf
     slow_t: np.ndarray | None = None        # (B, S, K) slow steps, inf pad
     slow_f: np.ndarray | None = None        # (B, S, K) factors, 1.0 pad
-    deg_t: np.ndarray | None = None         # (B, S, P, K2) edge steps, inf pad
-    deg_f: np.ndarray | None = None         # (B, S, P, K2) factors, 1.0 pad
+    deg_t: np.ndarray | None = None         # (D, S, P, K2) edge steps, inf pad
+    deg_f: np.ndarray | None = None         # (D, S, P, K2) factors, 1.0 pad
+    # scenario b reads row rows[b] of the P-wide edge arrays above, which
+    # hold D rows, one per distinct scenario; None: D = B, row b is b's
+    rows: np.ndarray | None = None          # (B,) int64, in [0, D)
 
     @property
     def has_faults(self) -> bool:
@@ -438,6 +444,14 @@ class ScenarioBatch:
         """(B, S) bool mask of rows the relaxation computes: real
         subtasks and join rows."""
         return np.arange(self.max_subtasks)[None, :] < self.n_rows[:, None]
+
+
+#: the array fields of a :class:`ScenarioBatch` with one row per
+#: scenario, and those with one row per distinct scenario (``rows`` maps
+#: the scenarios onto them)
+_ROW_FIELDS = ("n_sub", "n_rows", "duration", "release", "prev", "wave",
+               "t_est", "fail_t", "slow_t", "slow_f")
+_EDGE_FIELDS = ("pred", "pred_lat", "pred_volbw", "deg_t", "deg_f")
 
 
 #: widest row of a padded predecessor layout, the in-order column
@@ -707,17 +721,23 @@ def batch_scenarios(scenarios: list[ScenarioArrays]) -> ScenarioBatch:
 
 def relax_operands(batch: ScenarioBatch
                    ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """``(pred, lat, volbw)``, each ``(B, S, P+1)``: the batch's edges
+    """``(pred, lat, volbw)``, each ``(D, S, P+1)``: the batch's edges
     with the in-order core edge as the last column — ``prev`` as its
     source with zero lags where ``prev < S``, the sentinel ``S`` with
     ``-inf`` lags where a row has none. The one place that column is
     appended; every relaxation of a batch reads these operands
     (``repro.search.device.population_gather_inputs`` is the
-    device-side twin for a GA population). Cached on the batch."""
+    device-side twin for a GA population). One row per row of
+    ``batch.pred``: ``D = B`` unless ``batch.rows`` is set. Cached on
+    the batch."""
     cached = batch.__dict__.get("_relax_operands")
     if cached is not None:
         return cached
-    prev = batch.prev[:, :, None]
+    prev = batch.prev
+    if batch.rows is not None:
+        # one row per distinct scenario: each row's first scenario
+        prev = prev[np.unique(batch.rows, return_index=True)[1]]
+    prev = prev[:, :, None]
     inorder = np.where(prev < batch.max_subtasks, 0.0, -np.inf)
     cached = (np.concatenate([batch.pred, prev], axis=2),
               np.concatenate([batch.pred_lat, inorder], axis=2),
@@ -750,23 +770,58 @@ def lower_population(graph: AppGraph, machine: MachineModel,
     return batch_scenarios(scenarios)
 
 
+def expand_rows(batch: ScenarioBatch) -> ScenarioBatch:
+    """The batch with its edge arrays at one row per scenario
+    (``rows`` resolved on the host), for the host relaxations and the
+    analysis; the batch itself where it has no ``rows``. Cached on the
+    batch."""
+    if batch.rows is None:
+        return batch
+    full = batch.__dict__.get("_expanded")
+    if full is None:
+        full = replace(batch, rows=None, **{
+            f: _frozen(getattr(batch, f)[batch.rows]) for f in _EDGE_FIELDS
+            if getattr(batch, f) is not None})
+        object.__setattr__(batch, "_expanded", full)
+    return full
+
+
+def select_rows(batch: ScenarioBatch, rows) -> ScenarioBatch:
+    """A batch of ``len(rows)`` scenarios, scenario ``i`` being the
+    batch's scenario ``rows[i]``, each of them picked at least once —
+    the jitter- or seed-sweep shape: same scenarios, different draws.
+    The per-scenario fields are gathered, the edge arrays are shared
+    (``ScenarioBatch.rows``), so nothing is padded, walked or gathered
+    again. The counters of :func:`batch_scenarios` grow by the copies:
+    the relaxation gathers every scenario of the batch."""
+    if batch.rows is not None:
+        raise ValueError("select_rows takes a batch without a row map")
+    n = batch.n_scenarios
+    rows = np.array(rows, np.int64)
+    if rows.ndim != 1 or rows.min(initial=0) < 0 \
+            or rows.max(initial=0) >= n:
+        raise ValueError(f"rows must index the batch's {n} scenarios")
+    copies = np.bincount(rows, minlength=n) - 1
+    if copies.min() < 0:
+        raise ValueError("rows leave a scenario of the batch out")
+    s = batch.max_subtasks
+    edges = (batch.pred < batch.n_sub[:, None, None]).sum(axis=(1, 2)) \
+        + (batch.prev < s).sum(axis=1)  # the edges batch_scenarios counts
+    obs.count("lower.join_rows", int(copies @ (batch.n_rows - batch.n_sub)))
+    obs.count("lower.edge_slots",
+              int(copies.sum()) * s * (batch.max_preds + 1))
+    obs.count("lower.edges", int(copies @ edges))
+    return replace(batch, n_scenarios=len(rows), rows=_frozen(rows), **{
+        f: _frozen(getattr(batch, f)[rows]) for f in _ROW_FIELDS
+        if getattr(batch, f) is not None})
+
+
 def repeat_batch(batch: ScenarioBatch, k: int) -> ScenarioBatch:
-    """Tile a batch ``k`` times along the scenario axis (the jitter- or
-    seed-sweep shape: same scenarios, different draws) without paying
-    the batch construction again."""
+    """Tile a batch ``k`` times along the scenario axis
+    (:func:`select_rows`)."""
     if k <= 1:
         return batch
-    fields = ["n_sub", "n_rows", "duration", "release", "prev", "pred",
-              "pred_lat", "pred_volbw", "wave", "t_est"]
-    if batch.has_faults:
-        fields += ["fail_t", "slow_t", "slow_f", "deg_t", "deg_f"]
-    rep = {f: _frozen(np.tile(getattr(batch, f),
-                              (k,) + (1,) * (getattr(batch, f).ndim - 1)))
-           for f in fields}
-    return ScenarioBatch(
-        n_scenarios=batch.n_scenarios * k,
-        max_subtasks=batch.max_subtasks, max_preds=batch.max_preds,
-        depth=batch.depth, **rep)
+    return select_rows(batch, np.tile(np.arange(batch.n_scenarios), k))
 
 
 # ---------------------------------------------------------------------------

@@ -27,11 +27,15 @@ Two execution paths, both fed by :mod:`repro.core.lowering`:
   kernel (``kernels/sim_step.sim_relax_pop``). Every path reads the
   same padded (B, S, P+1) predecessor gathers,
   :func:`~repro.core.lowering.relax_operands` — O(B·S·P) memory, so
-  1k+-subtask suites fit.
+  1k+-subtask suites fit. A batch that repeats scenarios holds them at
+  one row per distinct scenario; the host paths expand them
+  (:func:`~repro.core.lowering.expand_rows`), the kernel's path on the
+  device.
 """
 
 from __future__ import annotations
 
+import functools
 import heapq
 from dataclasses import dataclass
 
@@ -39,7 +43,8 @@ import numpy as np
 
 from .. import obs
 from .lowering import (ScenarioArrays, ScenarioBatch, batch_scenarios,
-                       lower_scenario, relax_operands)
+                       expand_rows, lower_scenario, relax_operands,
+                       select_rows)
 from .machine import MachineModel
 from .mpaha import AppGraph
 from .simulator import SimResult
@@ -294,7 +299,10 @@ def relax_batch_np(batch: ScenarioBatch, duration: np.ndarray | None = None,
     :func:`~repro.core.lowering.relax_operands` with the sources
     flattened against the ``(B, S+1)`` end buffer and the lags prefolded
     to ``lat + vol/bw`` (one add per sweep; within 1 ulp of the event
-    simulator's two-add expression)."""
+    simulator's two-add expression). A batch with a row map is read
+    through :func:`~repro.core.lowering.expand_rows`, as in every host
+    relaxation."""
+    batch = expand_rows(batch)
     b, s, p = batch.n_scenarios, batch.max_subtasks, batch.max_preds
     dur = batch.duration if duration is None else duration
     steps = batch.depth if n_steps is None else n_steps
@@ -357,6 +365,7 @@ def relax_wave_np(batch: ScenarioBatch,
     predecessor values with the same expression) but every subtask is
     touched exactly once instead of once per sweep — the production
     CPU path for large suites."""
+    batch = expand_rows(batch)
     b, s = batch.n_scenarios, batch.max_subtasks
     dur = (batch.duration if duration is None else duration).reshape(-1)
     order, bounds, idx, lag, rel, target = _wave_plan(batch)
@@ -394,6 +403,7 @@ def relax_wave_faults(batch: ScenarioBatch,
     ``SimResult.subtask_end`` under faults. Fault-free scenarios inside
     a faulty batch take the same expressions with all-neutral factors
     (``x * 1.0`` is exact), so they match :func:`relax_wave_np`."""
+    batch = expand_rows(batch)
     b, s = batch.n_scenarios, batch.max_subtasks
     dur = (batch.duration if duration is None else duration).reshape(-1)
     order, bounds, idx, lag, rel, target = _wave_plan(batch)
@@ -494,7 +504,7 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
             batch = batch_scenarios(batch)
     if verify:
         from ..analysis.ir_lint import lint_batch
-        lint_batch(batch)
+        lint_batch(expand_rows(batch))
     with obs.span("suite.jitter"):
         dur = _jitter_durations(batch, jitter, seeds)
     if batch.has_faults:
@@ -521,16 +531,39 @@ def simulate_batch(batch: ScenarioBatch | list[ScenarioArrays], *,
         # accumulated ulps, f64 paths get the validator's 1e-9
         rtol = 1e-5 if backend == "pallas" and not batch.has_faults \
             else 1e-9
-        verify_batch_result(batch, result, duration=dur, rtol=rtol)
+        verify_batch_result(expand_rows(batch), result, duration=dur,
+                            rtol=rtol)
     return result
 
 
+@functools.cache
+def _take_rows():
+    """Jitted ``operands -> each[rows]``: the device side of a batch's
+    row map (``ScenarioBatch.rows``)."""
+    import jax
+    import jax.numpy as jnp
+    return jax.jit(lambda rows, operands: tuple(
+        jnp.take(x, rows, axis=0) for x in operands))
+
+
 def _relax_pallas(batch: ScenarioBatch, duration: np.ndarray) -> np.ndarray:
+    """The relaxation kernel on the batch. Where the batch maps its
+    scenarios onto fewer rows, the operands go to the device at one row
+    per distinct scenario and are expanded there, so the kernel gets,
+    bit for bit, the arrays of the expanded batch."""
+    from ..analysis.ir_lint import check_gather_bounds
     from ..kernels.ops import sim_relax_pop
+    if batch.rows is not None:
+        # an out-of-range row does not fail on the device: the take
+        # hands the kernel a filled or wrong row
+        check_gather_bounds(batch.rows, batch.pred.shape[0] - 1,
+                            "suite.rows")
     with obs.span("suite.gather"):
-        pred, lat, volbw = relax_operands(batch)
+        operands = relax_operands(batch)
     with obs.span("suite.relax"):
-        end = sim_relax_pop(pred, lat, volbw, duration, batch.release,
+        if batch.rows is not None:
+            operands = _take_rows()(batch.rows, operands)
+        end = sim_relax_pop(*operands, duration, batch.release,
                             n_steps=batch.depth)
         return np.asarray(end, np.float64)
 
@@ -546,7 +579,14 @@ def simulate_suite(graphs: list[AppGraph], machines, schedules, *,
     and evaluate them in one batched call. ``machines`` may be a single
     machine (shared by every scenario) or one per graph; ``faults`` a
     single fault script (shared) or one per graph (``None`` entries =
-    healthy)."""
+    healthy).
+
+    Scenarios whose ``(graph, machine, schedule, releases, faults)`` are
+    the very same objects (a jitter sweep passes ``[g] * n``) are
+    lowered, batched and gathered once, and only their jitter draws
+    differ; equal but distinct objects are lowered apart. Nothing is
+    kept between calls, so a schedule changed after one call is seen as
+    it is by the next."""
     if isinstance(machines, MachineModel):
         machines = [machines] * len(graphs)
     rel = releases if releases is not None else [None] * len(graphs)
@@ -559,9 +599,20 @@ def simulate_suite(graphs: list[AppGraph], machines, schedules, *,
             f"{len(machines)} machines, {len(schedules)} schedules, "
             f"{len(rel)} release maps, {len(faults)} fault scripts")
     obs.count("suite.scenarios", len(graphs))
+    first: dict[tuple, int] = {}        # ids of a scenario's parts -> row
+    distinct = []
+    rows = np.empty(len(graphs), np.int64)
+    for i, parts in enumerate(zip(graphs, machines, schedules, rel, faults)):
+        rows[i] = first.setdefault(tuple(map(id, parts)), len(distinct))
+        if rows[i] == len(distinct):
+            distinct.append(parts)
+    obs.count("suite.distinct", len(distinct))
     with obs.span("suite.lower"):
         scenarios = [lower_scenario(g, m, s, releases=r, faults=f)
-                     for g, m, s, r, f in zip(graphs, machines, schedules,
-                                              rel, faults)]
-    return simulate_batch(scenarios, jitter=jitter, seeds=seeds,
+                     for g, m, s, r, f in distinct]
+    with obs.span("suite.batch"):
+        batch = batch_scenarios(scenarios)
+        if len(distinct) < len(rows):
+            batch = select_rows(batch, rows)
+    return simulate_batch(batch, jitter=jitter, seeds=seeds,
                           backend=backend, verify=verify)

@@ -40,7 +40,9 @@ import sys
 import threading
 import time
 
-RING = 4096
+#: about 2,300 suite calls of seven spans each, about 10 MB of records: a
+#: minute of short suite calls stays whole
+RING = 16384
 
 _ring: collections.deque = collections.deque(maxlen=RING)
 _threads: list = []         # every thread's _State, for snapshot and reset

@@ -163,5 +163,7 @@ def test_suite_call_counts_its_scenarios():
         ["suite.lower", "suite.batch", "suite.jitter", "suite.gather",
          "suite.relax"]
     assert all(s["root"] == root["id"] for s in spans)
+    # the call repeats two scenarios three times: each is lowered once
+    assert root["counts"]["suite.distinct"] == 2
     assert root["counts"].get("lower.graph_arrays.hit", 0) \
-        + root["counts"].get("lower.graph_arrays.miss", 0) == b
+        + root["counts"].get("lower.graph_arrays.miss", 0) == 2

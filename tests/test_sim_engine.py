@@ -4,16 +4,22 @@ the batched relaxation must reproduce the analytic event semantics —
 single vs batched, NumPy CSR vs wave-scheduled vs the sparse Pallas
 kernel are all swept against each other."""
 
+import copy
+import dataclasses
+
 import numpy as np
 import pytest
 
+from repro import obs
+from repro.analysis import IRLintError
 from repro.core import (AppGraph, Schedule, SimResult, SynthParams,
                         batch_scenarios, dell_poweredge_1950,
                         engine_schedule, generate_app, heterogeneous_cluster,
                         hp_bl260c, lower_scenario, paper_suite_8core,
                         repeat_batch, simulate, simulate_arrays,
                         simulate_batch, simulate_scenario, simulate_suite)
-from repro.core.lowering import relax_operands
+from repro.core.lowering import (expand_rows, lower_population,
+                                 relax_operands, select_rows)
 from repro.core.machine import CommLevel, MachineModel
 from repro.core.sim_engine import relax_batch_np, relax_wave_np
 from repro.online import ArrivalParams, generate_workload, replay_fifo
@@ -203,6 +209,153 @@ def test_relax_operands_append_the_in_order_column():
         assert np.all(x[:, :, p][has_prev] == 0.0)
         assert np.all(x[:, :, p][~has_prev] == -np.inf)
     assert np.array_equal(relax_wave_np(batch), relax_batch_np(batch))
+
+
+# ---------------------------------------------------------------------------
+# a suite call lowers each distinct scenario once
+# ---------------------------------------------------------------------------
+
+def _repeated_suite(kind):
+    """(machine, graphs, schedules) of a small suite: §5.1 applications
+    on the 8-core machine, or Montage workflows whose ``mConcatFit``
+    joins are read through join rows."""
+    from repro.core.workflows import montage
+    if kind == "paper":
+        m = dell_poweredge_1950()
+        apps = paper_suite_8core(n_apps=3)
+    else:
+        m = hp_bl260c()
+        apps = [montage(4, 1), montage(4, 2)]
+    return m, apps, [engine_schedule(g, m) for g in apps]
+
+
+def _unshared(graphs, m, schedules):
+    """Every scenario's parts as objects of its own."""
+    return ([copy.deepcopy(g) for g in graphs],
+            [copy.deepcopy(m) for _ in graphs],
+            [copy.deepcopy(s) for s in schedules])
+
+
+def _suite_counts(*args, **kwargs):
+    res = simulate_suite(*args, **kwargs)
+    return res, obs.snapshot()["spans"][-1]["counts"]
+
+
+@pytest.mark.parametrize("kind", ["paper", "montage"])
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_repeated_scenarios_lowered_once_same_results(backend, kind):
+    m, apps, schedules = _repeated_suite(kind)
+    d = 3
+    graphs, scheds = apps * d, schedules * d
+    seeds = range(500, 500 + len(graphs))
+    got, counts = _suite_counts(graphs, m, scheds, jitter=0.01,
+                                seeds=seeds, backend=backend)
+    assert counts["suite.distinct"] == len(apps)
+    assert counts["suite.scenarios"] == len(graphs)
+    want, full = _suite_counts(*_unshared(graphs, m, scheds), jitter=0.01,
+                               seeds=seeds, backend=backend)
+    assert full["suite.distinct"] == len(graphs)
+    assert np.array_equal(got.t_exec, want.t_exec)
+    assert np.array_equal(got.subtask_end, want.subtask_end)
+    # the counters still count what the relaxation gathers: B scenarios
+    for key in ("lower.edge_slots", "lower.edges", "lower.join_rows"):
+        assert counts.get(key, 0) == full.get(key, 0)
+    if kind == "montage":
+        assert counts["lower.join_rows"] > 0
+    again = simulate_suite(graphs, m, scheds, jitter=0.01, seeds=seeds,
+                           backend=backend, verify=True)
+    assert np.array_equal(again.t_exec, got.t_exec)
+
+
+@pytest.mark.parametrize("part", ["releases", "faults"])
+def test_repeated_parts_with_other_releases_or_faults_not_merged(part):
+    from repro.faults import FaultScript, core_fail
+    m, apps, schedules = _repeated_suite("paper")
+    g, sched = apps[0], schedules[0]
+    if part == "releases":
+        kw = {"releases": [None, {0: 0.0}, {0: 5.0}, {0: 5.0}]}
+    else:
+        late = FaultScript((core_fail(sched.makespan() * 0.5, 0),))
+        kw = {"faults": [None, late, FaultScript(()), late]}
+    got, counts = _suite_counts([g] * 4, m, [sched] * 4, jitter=0.01,
+                                seeds=range(4), verify=True, **kw)
+    # the last scenario repeats the third's parts only where they are
+    # one object: equal dicts are kept apart, one script is shared
+    assert counts["suite.distinct"] == (4 if part == "releases" else 3)
+    graphs, machines, scheds = _unshared([g] * 4, m, [sched] * 4)
+    want = simulate_suite(graphs, machines, scheds, jitter=0.01,
+                          seeds=range(4), **copy.deepcopy(kw))
+    assert np.array_equal(got.t_exec, want.t_exec)
+    assert np.array_equal(got.subtask_end, want.subtask_end)
+
+
+def test_schedule_changed_between_calls_is_seen():
+    m, apps, schedules = _repeated_suite("paper")
+    g, sched = apps[0], copy.deepcopy(schedules[0])
+    before = simulate_suite([g] * 2, m, [sched] * 2)
+    last = max(sched.placements.values(), key=lambda p: p.end)
+    sched.placements[last.sid] = dataclasses.replace(
+        last, start=last.start + 7.0, end=last.end + 7.0)
+    after = simulate_suite([g] * 2, m, [sched] * 2)
+    assert np.all(after.t_est == before.t_est + 7.0)
+
+
+@pytest.mark.parametrize("plant", ["pred", "rows"])
+def test_deduplicated_pallas_path_checks_its_indices(plant):
+    m, apps, schedules = _repeated_suite("paper")
+    scens = [lower_scenario(g, m, s) for g, s in zip(apps, schedules)]
+    batch = select_rows(batch_scenarios(scens), [0, 1, 2, 0, 1, 2])
+    assert batch.rows is not None and batch.pred.shape[0] == 3
+    if plant == "pred":
+        pred = np.array(batch.pred)
+        pred[1, 0, 0] = batch.max_subtasks + 1      # past the sentinel
+        bad = dataclasses.replace(batch, pred=pred)
+    else:
+        bad = dataclasses.replace(batch, rows=np.array([0, 1, 2, 0, 3, 2]))
+    with pytest.raises(IRLintError):
+        simulate_batch(bad, backend="pallas")
+    with pytest.raises(ValueError):
+        select_rows(batch_scenarios(scens), [0, 1, 3])
+    with pytest.raises(ValueError):
+        select_rows(batch_scenarios(scens), [0, 1, 1])  # scenario 2 left out
+
+
+def test_repeated_batch_expands_to_the_full_batch():
+    m, apps, schedules = _repeated_suite("montage")
+    scens = [lower_scenario(g, m, s) for g, s in zip(apps, schedules)]
+    rows = [1, 0, 1, 1]
+    compact = select_rows(batch_scenarios(scens), rows)
+    full = batch_scenarios([scens[r] for r in rows])
+    expanded = expand_rows(compact)
+    assert expanded.rows is None and expand_rows(compact) is expanded
+    for f in dataclasses.fields(full):
+        a, b = getattr(expanded, f.name), getattr(full, f.name)
+        assert np.array_equal(a, b) if isinstance(b, np.ndarray) else a == b
+    ops_c, ops_f = relax_operands(compact), relax_operands(full)
+    for c, f in zip(ops_c, ops_f):
+        assert c.shape[0] == 2
+        assert np.array_equal(c[compact.rows], f)
+    # the host relaxations read a row-mapped batch as its expansion
+    for relax in (relax_wave_np, relax_batch_np):
+        assert np.array_equal(relax(compact), relax(full))
+
+
+def test_host_ga_population_batch_unchanged():
+    """The host GA's candidates are all distinct: its batch maps no rows
+    and its fitness is the suite's over the same schedules."""
+    from repro.search.ga import decode_population, population_fitness
+    app = generate_app(SynthParams(n_tasks=(8, 12)), seed=4)
+    m = dell_poweredge_1950()
+    pop = np.random.default_rng(2).integers(0, m.n_cores,
+                                            (12, len(app.tasks)))
+    scheds = decode_population(app, m, pop)
+    batch = lower_population(app, m, scheds)
+    assert batch.rows is None
+    fit = population_fitness(app, m, pop)
+    suite, counts = _suite_counts([app] * len(scheds), m, scheds)
+    assert counts["suite.distinct"] == len(scheds)
+    assert np.array_equal(fit, simulate_batch(batch).t_exec)
+    assert np.array_equal(fit, suite.t_exec)
 
 
 # ---------------------------------------------------------------------------
